@@ -66,6 +66,9 @@ def main() -> None:
         print(f"unknown benchmark(s): {', '.join(sorted(unknown))}")
         print(f"available: {', '.join(sorted(known))}")
         sys.exit(2)
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     outcomes = []  # (name, error-or-None), in run order
     for name, module in BENCHES:
         if selected and name not in selected:

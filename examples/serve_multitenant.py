@@ -7,7 +7,8 @@ workloads (common system prompts / RAG chunks); tenant C is disjoint.
 The engine admits tenants with the working-set controller, shares prefix
 blocks per the paper's LRU-list apportionment, and decodes with a real
 (reduced) model. Finally, the shared-prefix Pallas kernel is
-demonstrated on a grouped batch against its jnp oracle.
+demonstrated on a grouped batch against its jnp oracle, in the Pallas
+interpreter (``interpret=True``) so that it runs on any host.
 """
 
 import dataclasses

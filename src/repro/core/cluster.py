@@ -655,7 +655,14 @@ class ClusterExecutor:
     threadpools hold locks a forked child could inherit mid-acquire —
     safe here because workers only ever execute numpy and the
     fastsim C/flat drivers, never JAX, so no inherited JAX lock is
-    ever taken."""
+    ever taken. The same argument covers a parent that has already
+    driven a TPU: the children never touch the device its client
+    holds. What has been run: on a TPU v5e host, ``python -m
+    benchmarks.run simthroughput cluster`` (XLA on the chip, then this
+    executor) completed once with ``--quick`` and twice at the default
+    scale, each time printing JAX's fork warning and each time
+    bit-identical to the sequential executor. That is evidence, not a
+    proof that no fork can deadlock."""
 
     def __init__(
         self,

@@ -1042,16 +1042,21 @@ def make_chunk_driver(
                     "engine='c' requested but the C backend is unavailable"
                 )
         if driver is None and engine == "xla":
-            if params.batch_interval == 0 and _xla_applicable(
+            if params.batch_interval or not _xla_applicable(
                 int(n_requests), N, lengths_a, params
             ):
-                driver = _make_xla(params, N, lengths_a, warmup, ripple_from, scale)
-                engine_name = "xla"
-            if driver is None:
                 raise RuntimeError(
                     "engine='xla' requested but the XLA driver is not applicable "
-                    "(jax missing, batch_interval > 0, or int32 range exceeded)"
+                    "(batch_interval > 0, or int32 range exceeded)"
                 )
+            try:
+                driver = _make_xla(params, N, lengths_a, warmup, ripple_from, scale)
+            except Exception as e:
+                raise RuntimeError(
+                    "engine='xla' requested but the XLA driver could not be "
+                    f"built: {type(e).__name__}: {e}"
+                ) from e
+            engine_name = "xla"
         if driver is None and engine == "generic":
             driver = _GenericDriver(params, N, lengths_a, warmup, ripple_from)
             engine_name = "generic"
@@ -1129,14 +1134,11 @@ def _make_c_noshare(params, n_objects, lengths, warmup):
 
 
 def _make_xla(params, n_objects, lengths, warmup, ripple_from, scale):
-    try:
-        from . import fastsim_jax
+    from . import fastsim_jax
 
-        return fastsim_jax.XLAChunkRunner(
-            params, n_objects, lengths, warmup, ripple_from, scale
-        )
-    except Exception:
-        return None
+    return fastsim_jax.XLAChunkRunner(
+        params, n_objects, lengths, warmup, ripple_from, scale
+    )
 
 
 def _xla_applicable(

@@ -67,7 +67,12 @@ def expected_inverse_one_plus(h_others: jnp.ndarray, n_quad: int) -> jnp.ndarray
     w = jnp.asarray(w, h_others.dtype)
     # terms: (..., J-1, Q) -> product over J-1 -> weighted sum over Q.
     terms = 1.0 - h_others[..., None] * (1.0 - x)
-    return jnp.prod(terms, axis=-2) @ w
+    # A guard: the TPU's default f32 dot rounds its inputs to bf16. It
+    # only matters where XLA emits a dot for this contraction; the
+    # solver's TPU executables today hold none.
+    return jnp.matmul(
+        jnp.prod(terms, axis=-2), w, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 def _l1_matrix(h: jnp.ndarray, n_quad: int) -> jnp.ndarray:

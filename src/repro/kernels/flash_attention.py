@@ -14,8 +14,8 @@ FA2-style tiling for the MXU/VMEM hierarchy:
 * GQA: kv tiles are indexed by ``q_head // group_size``, so grouped query
   heads reuse the same staged KV tile.
 
-Validated against ``ref.reference_attention`` in interpret mode (this
-container is CPU-only; TPU is the deploy target).
+Validated against ``ref.reference_attention`` in interpret mode on the
+host; ``tests/test_tpu_compile.py`` compiles it for a TPU v5e.
 """
 
 from __future__ import annotations

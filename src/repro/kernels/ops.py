@@ -1,15 +1,16 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies execute in Python via the Pallas interpreter, which is the
-validation mode for the TPU target). On TPU backends the default flips
-to compiled Mosaic.
+The kernels compile to Mosaic for the TPU. ``interpret=True`` runs the
+kernel bodies in the Pallas interpreter instead, which is how they are
+checked on a host without a TPU; it is never chosen implicitly, so a
+kernel that the TPU compiler refuses fails loudly rather than running
+interpreted.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,18 +21,12 @@ from .paged_attention import paged_attention as _paged
 from .shared_prefix_attention import shared_prefix_attention as _shared_prefix
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv", "interpret"))
 def flash_attention(
     q, k, v, *, causal: bool = True, block_q: int = 128, block_kv: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """(B,T,H,D) x (B,S,KV,D) -> (B,T,H,D); FA2 tiling, causal block skip."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _flash(
         q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
         interpret=interpret,
@@ -41,12 +36,10 @@ def flash_attention(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(
     q, k_pages, v_pages, block_tables, context_lens, *,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Decode attention through block tables over the shared physical
     KV pool. (B,H,D) -> (B,H,D)."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _paged(
         q, k_pages, v_pages, block_tables, context_lens, interpret=interpret
     )
@@ -55,29 +48,28 @@ def paged_attention(
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def shared_prefix_attention(
     q, prefix_k, prefix_v, prefix_lens, *, block_s: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Grouped attention of all requests sharing a prefix against its one
     physical KV copy. Returns (out, lse) for merging."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _shared_prefix(
         q, prefix_k, prefix_v, prefix_lens, block_s=block_s, interpret=interpret
     )
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def shared_prefix_decode(
     q,                 # (P, M, H, D) grouped queries
     prefix_k, prefix_v, prefix_lens,       # shared objects (one copy each)
     suffix_k, suffix_v,                    # (P, M, Ss, KV, D) per-request
     suffix_lens,                           # (P, M)
+    *,
+    interpret: bool = False,
 ):
     """Full object-sharing decode: shared-prefix kernel + per-request
     suffix attention, LSE-merged. The physical prefix KV is read once per
     GROUP (not once per request) — the compute analogue of the paper's
     l_n/|P(n)| apportioning."""
-    interpret = _default_interpret()
     out_a, lse_a = _shared_prefix(
         q, prefix_k, prefix_v, prefix_lens, interpret=interpret
     )

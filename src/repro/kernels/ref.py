@@ -124,3 +124,45 @@ def reference_shared_prefix_attention(
     return reference_attention_with_lse(
         q, prefix_k, prefix_v, sm_scale=sm_scale, kv_valid_len=prefix_lens
     )
+
+
+def reference_shared_prefix_decode(
+    q: jnp.ndarray,            # (P, M, H, D) queries grouped by prefix
+    prefix_k: jnp.ndarray,     # (P, S, KV, D) one physical copy per prefix
+    prefix_v: jnp.ndarray,     # (P, S, KV, D)
+    prefix_lens: jnp.ndarray,  # (P,)
+    suffix_k: jnp.ndarray,     # (P, M, Ss, KV, D) per-request suffixes
+    suffix_v: jnp.ndarray,     # (P, M, Ss, KV, D)
+    suffix_lens: jnp.ndarray,  # (P, M)
+    *,
+    sm_scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """Shared-prefix decode as ONE dense attention per request over its
+    prefix's valid KV followed by its own valid suffix KV — what the
+    kernel computes in two LSE-merged parts. Returns (P, M, H, D)."""
+    P, M, H, D = q.shape
+    S, KV = prefix_k.shape[1], prefix_k.shape[2]
+    Ss = suffix_k.shape[2]
+    # position j of the concatenation reads prefix row j below the prefix
+    # length and suffix row j - prefix_len from there on
+    j = jnp.arange(S + Ss)[None, None, :]
+    plen = prefix_lens[:, None, None]
+    src = jnp.where(j < plen, j, S + j - plen)
+    src = jnp.broadcast_to(jnp.minimum(src, S + Ss - 1), (P, M, S + Ss))
+
+    def concat(prefix, suffix):
+        both = jnp.concatenate(
+            [jnp.broadcast_to(prefix[:, None], (P, M, S, KV, D)), suffix], 2
+        )
+        rows = jnp.take_along_axis(both, src[..., None, None], axis=2)
+        return rows.reshape(P * M, S + Ss, KV, D)
+
+    out = reference_attention(
+        q.reshape(P * M, 1, H, D),
+        concat(prefix_k, suffix_k),
+        concat(prefix_v, suffix_v),
+        causal=False,
+        sm_scale=sm_scale,
+        kv_valid_len=(plen[:, :, 0] + suffix_lens).reshape(P * M),
+    )
+    return out.reshape(P, M, H, D)

@@ -85,7 +85,7 @@ def _prefix_kernel(
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).astype(lse_ref.dtype)
+        lse_ref[0, 0, 0] = (m_ref[...] + jnp.log(l)).astype(lse_ref.dtype)
 
 
 def shared_prefix_attention(
@@ -126,7 +126,10 @@ def shared_prefix_attention(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, M * G, D), lambda p, h, i, pls: (p, h, 0, 0)),
-            pl.BlockSpec((1, 1, M * G), lambda p, h, i, pls: (p, h, 0)),
+            # a unit axis keeps the block's last two dims (1, M*G) equal
+            # to the array's, as the TPU lowering requires of a block
+            # whose second-minor dim is neither 8-aligned nor full
+            pl.BlockSpec((1, 1, 1, M * G), lambda p, h, i, pls: (p, h, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((M * G, D), jnp.float32),
@@ -139,7 +142,7 @@ def shared_prefix_attention(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((P, KV, M * G, D), q.dtype),
-            jax.ShapeDtypeStruct((P, KV, M * G), jnp.float32),
+            jax.ShapeDtypeStruct((P, KV, 1, M * G), jnp.float32),
         ],
         interpret=interpret,
     )(prefix_lens, qr, kh, vh)

@@ -12,17 +12,11 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types (Auto == pre-0.5 behaviour)
-    from jax.sharding import AxisType
 
-    def _mesh_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-
-except ImportError:  # pragma: no cover - older jax: Auto is implicit
-
-    def _mesh_kwargs(n_axes: int) -> dict:
-        return {}
+def _mesh_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -230,6 +230,23 @@ def test_xla_backend_equals_python_flat():
     assert a.n_hit_list == b.n_hit_list and a.n_miss == b.n_miss
 
 
+def test_xla_driver_build_error_is_not_masked(monkeypatch):
+    """A failure to build the XLA driver (e.g. a device allocation
+    error) reaches the caller as the cause of the error it gets."""
+    from repro.core import fastsim_jax
+
+    def fail(*args, **kwargs):
+        raise MemoryError("RESOURCE_EXHAUSTED: device allocation failed")
+
+    monkeypatch.setattr(fastsim_jax, "XLAChunkRunner", fail)
+    lam = rate_matrix(200, [0.8, 1.0])
+    trace = sample_trace(lam, 1_000, seed=3)
+    p = SimParams(allocations=(8, 8), physical_capacity=200)
+    with pytest.raises(RuntimeError, match="could not be built") as err:
+        simulate_trace(p, trace, 200, engine="xla")
+    assert isinstance(err.value.__cause__, MemoryError)
+
+
 def test_noshare_variant_matches_reference_baseline(small_trace):
     trace, N = small_trace
     warmup = 5_000

@@ -1,5 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret
-mode (TPU is the deploy target; this container is CPU-only)."""
+mode, which every call here asks for explicitly. Their TPU compiles are
+checked in ``tests/test_tpu_compile.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +102,35 @@ def test_shared_prefix_attention_matches_reference(P, M, H, KV, D, S, dtype):
         got_o.astype(jnp.float32), want_o, atol=_tol(dtype)
     )
     np.testing.assert_allclose(got_l, want_l, atol=5e-2 if dtype == jnp.bfloat16 else 1e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "P,M,H,KV,D,S,Ss",
+    [
+        (2, 3, 8, 4, 64, 100, 24),   # non-aligned prefix blocks
+        (1, 4, 4, 1, 128, 256, 16),  # MQA
+    ],
+)
+def test_shared_prefix_decode_matches_attention_over_prefix_and_suffix(
+    P, M, H, KV, D, S, Ss, dtype
+):
+    ks = jax.random.split(RNG, 7)
+    q = jax.random.normal(ks[0], (P, M, H, D), dtype)
+    pk = jax.random.normal(ks[1], (P, S, KV, D), dtype)
+    pv = jax.random.normal(ks[2], (P, S, KV, D), dtype)
+    sk = jax.random.normal(ks[3], (P, M, Ss, KV, D), dtype)
+    sv = jax.random.normal(ks[4], (P, M, Ss, KV, D), dtype)
+    plens = jax.random.randint(ks[5], (P,), 1, S + 1)
+    slens = jax.random.randint(ks[6], (P, M), 1, Ss + 1)
+    got = ops.shared_prefix_decode(
+        q, pk, pv, plens, sk, sv, slens, interpret=True
+    )
+    want = ref.reference_shared_prefix_decode(q, pk, pv, plens, sk, sv, slens)
+    assert got.shape == q.shape and got.dtype == dtype
+    np.testing.assert_allclose(
+        got.astype(jnp.float32), want.astype(jnp.float32), atol=_tol(dtype)
+    )
 
 
 def test_lse_merge_equals_joint_attention():

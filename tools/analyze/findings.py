@@ -9,10 +9,10 @@ intentional exception lives in one reviewed file with a reason string.
 
 from __future__ import annotations
 
-import re
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 
 @dataclass
@@ -76,52 +76,13 @@ class Waiver:
 # ---------------------------------------------------------------------------
 # waivers.toml loading
 # ---------------------------------------------------------------------------
-_KV_RE = re.compile(r'^([A-Za-z_][A-Za-z0-9_]*)\s*=\s*"((?:[^"\\]|\\.)*)"\s*$')
-
-
-def _parse_waiver_toml(text: str) -> List[Dict[str, str]]:
-    """Minimal TOML-subset parser for the waiver file.
-
-    Python 3.10 (the CI floor) has no ``tomllib``; rather than grow a
-    dependency for one config file, parse the subset the file actually
-    uses: ``[[waiver]]`` array-of-tables headers and ``key = "string"``
-    pairs. ``tomllib``, when available, is preferred (and the test suite
-    cross-checks both parsers agree on the shipped file).
-    """
-    entries: List[Dict[str, str]] = []
-    current: Optional[Dict[str, str]] = None
-    for n, raw in enumerate(text.splitlines(), 1):
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line == "[[waiver]]":
-            current = {}
-            entries.append(current)
-            continue
-        m = _KV_RE.match(line)
-        if m:
-            if current is None:
-                raise ValueError(
-                    f"waivers.toml:{n}: key outside a [[waiver]] table"
-                )
-            key, val = m.group(1), m.group(2)
-            current[key] = val.replace('\\"', '"').replace("\\\\", "\\")
-            continue
-        raise ValueError(f"waivers.toml:{n}: unparseable line {raw!r}")
-    return entries
 
 
 def load_waivers(path: Path) -> List[Waiver]:
     """Load ``waivers.toml`` (missing file = no waivers)."""
     if not path.exists():
         return []
-    text = path.read_text()
-    try:
-        import tomllib  # Python >= 3.11
-
-        entries = tomllib.loads(text).get("waiver", [])
-    except ModuleNotFoundError:
-        entries = _parse_waiver_toml(text)
+    entries = tomllib.loads(path.read_text()).get("waiver", [])
     waivers = []
     for i, e in enumerate(entries):
         unknown = set(e) - {"rule", "path", "reason", "code", "contains"}
