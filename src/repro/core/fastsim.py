@@ -84,6 +84,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
+
 from .irm import IRMTrace
 from .shared_lru import GetResult, _lcm_1_to
 
@@ -980,10 +982,11 @@ def simulate_chunks(
         raise ValueError(
             f"chunk stream supplied {consumed} requests but n_requests={n}"
         )
-    out = driver.finish(n)
-    return _assemble(
-        out, driver.elapsed, n, warmup, J, N, vlen_scale, engine_name, sparse
-    )
+    with spans.span("mc.collect"):
+        out = driver.finish(n)
+        return _assemble(
+            out, driver.elapsed, n, warmup, J, N, vlen_scale, engine_name, sparse
+        )
 
 
 def make_chunk_driver(
@@ -1136,9 +1139,11 @@ def _make_c_noshare(params, n_objects, lengths, warmup):
 def _make_xla(params, n_objects, lengths, warmup, ripple_from, scale):
     from . import fastsim_jax
 
-    return fastsim_jax.XLAChunkRunner(
-        params, n_objects, lengths, warmup, ripple_from, scale
-    )
+    # constants and fresh engine state onto the device
+    with spans.span("mc.feed"):
+        return fastsim_jax.XLAChunkRunner(
+            params, n_objects, lengths, warmup, ripple_from, scale
+        )
 
 
 def _xla_applicable(

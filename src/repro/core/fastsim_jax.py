@@ -64,7 +64,6 @@ variants run on the pure-Python loops (see ``fastsim.simulate_trace``).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -72,6 +71,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro import spans
 
 from .fastsim import HIST_BUCKETS
 
@@ -354,11 +355,15 @@ def _drive_impl(
 
 @functools.lru_cache(maxsize=None)
 def _single_fn(ghost_retention: bool, n_objects: int):
-    """Jitted single-replica driver (state donated) for one flag set."""
-    f = functools.partial(
-        _drive_impl, ghost_retention=ghost_retention, n_objects=n_objects
-    )
-    return jax.jit(f, donate_argnums=(0,))
+    """Jitted single-replica driver (state donated) for one flag set,
+    named ``jit_mc_drive`` in a device trace."""
+
+    def mc_drive(*args):
+        return _drive_impl(
+            *args, ghost_retention=ghost_retention, n_objects=n_objects
+        )
+
+    return jax.jit(mc_drive, donate_argnums=(0,))
 
 
 def _drive_batched_impl(
@@ -644,13 +649,15 @@ _UNROLL = 2
 
 @functools.lru_cache(maxsize=None)
 def _batched_fn(ghost_retention: bool, n_objects: int):
-    """Jitted R-replica ensemble driver (state donated)."""
-    f = functools.partial(
-        _drive_batched_impl,
-        ghost_retention=ghost_retention,
-        n_objects=n_objects,
-    )
-    return jax.jit(f, donate_argnums=(0,))
+    """Jitted R-replica ensemble driver (state donated), named
+    ``jit_mc_drive_lanes`` in a device trace."""
+
+    def mc_drive_lanes(*args):
+        return _drive_batched_impl(
+            *args, ghost_retention=ghost_retention, n_objects=n_objects
+        )
+
+    return jax.jit(mc_drive_lanes, donate_argnums=(0,))
 
 
 # Global AOT executable cache. A compiled driver depends only on the
@@ -748,15 +755,16 @@ class _RunnerBase:
                 # the executable for every later same-shape feed — the
                 # warm-up is the real compiled object, not a hint to a
                 # version-dependent jit cache.
-                ex = self._fn().lower(*args).compile()
+                with spans.span("mc.compile"):
+                    ex = self._fn().lower(*args).compile()
                 _AOT_CACHE[key] = ex
                 self.n_compiles += 1
             self._compiled[m] = ex
-        t0 = time.perf_counter()
-        st = ex(*args)
-        for leaf in jax.tree_util.tree_leaves(st):
-            leaf.block_until_ready()
-        self.elapsed += time.perf_counter() - t0
+        with spans.timed("mc.drive") as drive:
+            st = ex(*args)
+            for leaf in jax.tree_util.tree_leaves(st):
+                leaf.block_until_ready()
+        self.elapsed += drive.seconds
         self.st = st
         self.idx += m
 
@@ -824,8 +832,9 @@ class XLAChunkRunner(_RunnerBase):
         return _single_fn(self.ghost_retention, self.N)
 
     def feed(self, proxies, objects) -> None:
-        P = jnp.asarray(np.asarray(proxies), jnp.int32)
-        O = jnp.asarray(np.asarray(objects), jnp.int32)
+        with spans.span("mc.feed"):
+            P = jnp.asarray(np.asarray(proxies), jnp.int32)
+            O = jnp.asarray(np.asarray(objects), jnp.int32)
         self._feed_arrays(P, O)
 
     def finish(self, n_total: int) -> Dict[str, np.ndarray]:
@@ -907,8 +916,9 @@ class BatchedXLARunner(_RunnerBase):
         return _batched_fn(self.ghost_retention, self.N)
 
     def feed(self, proxies, objects) -> None:
-        P = jnp.asarray(np.asarray(proxies), jnp.int32)
-        O = jnp.asarray(np.asarray(objects), jnp.int32)
+        with spans.span("mc.feed"):
+            P = jnp.asarray(np.asarray(proxies), jnp.int32)
+            O = jnp.asarray(np.asarray(objects), jnp.int32)
         if P.ndim != 2 or P.shape[0] != self.R:
             raise ValueError(
                 f"ensemble feed expects stacked (R={self.R}, m) chunks, "
@@ -990,9 +1000,11 @@ def simulate_ensemble(
         )
     J = len(params.allocations)
     scale = _lcm_1_to(J)
-    runner = BatchedXLARunner(
-        params, N, lengths_a, warmup, ripple_from, scale, R
-    )
+    # constants and fresh engine state onto the device
+    with spans.span("mc.feed"):
+        runner = BatchedXLARunner(
+            params, N, lengths_a, warmup, ripple_from, scale, R
+        )
     if streamed:
         consumed = 0
         for group in zip(*traces):
@@ -1001,10 +1013,10 @@ def simulate_ensemble(
                 raise ValueError(
                     "replica chunk streams must yield equal-length chunks"
                 )
-            runner.feed(
-                np.stack([np.asarray(c.proxies) for c in group]),
-                np.stack([np.asarray(c.objects) for c in group]),
-            )
+            with spans.span("mc.feed"):
+                P = np.stack([np.asarray(c.proxies) for c in group])
+                O = np.stack([np.asarray(c.objects) for c in group])
+            runner.feed(P, O)
             consumed += m
         if consumed != n:
             raise ValueError(
@@ -1012,14 +1024,15 @@ def simulate_ensemble(
                 f"n_requests={n}"
             )
     else:
-        runner.feed(
-            np.stack([np.asarray(t.proxies) for t in traces]),
-            np.stack([np.asarray(t.objects) for t in traces]),
-        )
-    outs = runner.finish(n)
-    return [
-        _assemble(
-            out, runner.elapsed, n, warmup, J, N, scale, "xla", sparse
-        )
-        for out in outs
-    ]
+        with spans.span("mc.feed"):
+            P = np.stack([np.asarray(t.proxies) for t in traces])
+            O = np.stack([np.asarray(t.objects) for t in traces])
+        runner.feed(P, O)
+    with spans.span("mc.collect"):
+        outs = runner.finish(n)
+        return [
+            _assemble(
+                out, runner.elapsed, n, warmup, J, N, scale, "xla", sparse
+            )
+            for out in outs
+        ]

@@ -42,7 +42,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
+
 ATTRIBUTIONS = ("L1", "Lstar", "L2", "full")
+GROW_STEPS = 64  # bracket-growth steps of every inner solve
 
 
 def hit_probabilities(lam: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
@@ -155,7 +158,14 @@ def _solve_jax(
     damping: float,
     tol: float,
 ):
-    """Damped Jacobi outer loop + vectorized inner bisection. jit-able."""
+    """Damped Jacobi outer loop + vectorized inner bisection. jit-able.
+
+    Returns ``t, h, L, res, iters, delta`` and ``work``: int32
+    ``[grown, bisected]``, the bracket-growth and bisection steps, summed
+    over all ``iters + 1`` inner solves, that moved the bracket of at
+    least one proxy (each inner solve evaluates the residual
+    ``GROW_STEPS + n_bisect`` times).
+    """
     J, N = lam.shape
 
     def residual_all(t_cand: jnp.ndarray, h_frozen: jnp.ndarray) -> jnp.ndarray:
@@ -179,46 +189,62 @@ def _solve_jax(
             L = lengths[None, :]
         return (h_own * L).sum(axis=1) - b
 
-    def inner_solve(h_frozen: jnp.ndarray) -> jnp.ndarray:
+    def inner_solve(h_frozen: jnp.ndarray):
+        """t with g_i(t_i) = 0 for every proxy, and how many of the
+        bracket-growth and bisection steps moved the bracket of at least
+        one proxy.
+
+        Row i of the residual depends on t_i alone, so a step that leaves
+        proxy i's bracket as it was leaves it so for good: the steps that
+        move it are a prefix, and the steps that move any proxy's number
+        the longest prefix. Counting per proxy keeps the count inside the
+        loops' vector work.
+        """
         # Bracket: grow hi until residual positive (or cap).
         hi0 = jnp.full((J,), 1e-2, lam.dtype)
+        none = jnp.zeros((J,), jnp.int32)
 
-        def grow(_, hi):
+        def grow(_, carry):
+            hi, moved = carry
             g = residual_all(hi, h_frozen)
-            return jnp.where(g < 0, hi * 4.0, hi)
+            hi_next = jnp.where(g < 0, hi * 4.0, hi)
+            return hi_next, moved + (hi_next != hi)
 
-        hi = jax.lax.fori_loop(0, 64, grow, hi0)
+        hi, grown = jax.lax.fori_loop(0, GROW_STEPS, grow, (hi0, none))
         lo = jnp.zeros((J,), lam.dtype)
 
-        def bisect(_, lohi):
-            lo, hi = lohi
+        def bisect(_, carry):
+            lo, hi, moved = carry
             mid = 0.5 * (lo + hi)
             g = residual_all(mid, h_frozen)
-            lo = jnp.where(g < 0, mid, lo)
-            hi = jnp.where(g < 0, hi, mid)
-            return lo, hi
+            lo_next = jnp.where(g < 0, mid, lo)
+            hi_next = jnp.where(g < 0, hi, mid)
+            return lo_next, hi_next, moved + ((lo_next != lo) | (hi_next != hi))
 
-        lo, hi = jax.lax.fori_loop(0, n_bisect, bisect, (lo, hi))
-        return 0.5 * (lo + hi)
+        lo, hi, bisected = jax.lax.fori_loop(0, n_bisect, bisect, (lo, hi, none))
+        return 0.5 * (lo + hi), jnp.stack([grown.max(), bisected.max()])
 
     def outer(state):
-        t, it, _ = state
+        t, it, _, work = state
         h_frozen = hit_probabilities(lam, t)
-        t_new = inner_solve(h_frozen)
+        t_new, moved = inner_solve(h_frozen)
         t_next = (1.0 - damping) * t + damping * t_new
         delta = jnp.max(jnp.abs(t_next - t) / jnp.maximum(t, 1e-12))
-        return t_next, it + 1, delta
+        return t_next, it + 1, delta, work + moved
 
     def cond(state):
-        _, it, delta = state
+        _, it, delta, _ = state
         return jnp.logical_and(it < n_outer, delta > tol)
 
-    t0 = inner_solve(jnp.zeros((J, N), lam.dtype))  # not-shared warm start
-    t, iters, delta = jax.lax.while_loop(cond, outer, (t0, 0, jnp.inf))
+    # not-shared warm start
+    t0, work0 = inner_solve(jnp.zeros((J, N), lam.dtype))
+    t, iters, delta, work = jax.lax.while_loop(
+        cond, outer, (t0, 0, jnp.inf, work0)
+    )
     h = hit_probabilities(lam, t)
     L = attribution_matrix(h, lengths, kind, n_quad)
     res = b - (h * L).sum(axis=1)
-    return t, h, L, res, iters, delta
+    return t, h, L, res, iters, delta, work
 
 
 @functools.lru_cache(maxsize=None)
@@ -237,20 +263,27 @@ def _jitted_solver(
     ``functools.partial`` in ``jax.jit``, so the Table-II sweep paid 8
     compilations for 8 identical-shape solves. The cache reuses the
     executable; ``batched=True`` additionally ``vmap``s over a batch of
-    allocation vectors so a whole ``b``-grid is one compiled call.
+    allocation vectors so a whole ``b``-grid is one compiled call. The
+    executables are named ``jit_ws_solve`` and ``jit_ws_solve_batch``.
     """
-    fn = functools.partial(
-        _solve_jax,
-        kind=kind,
-        n_quad=n_quad,
-        n_outer=n_outer,
-        n_bisect=n_bisect,
-        damping=damping,
-        tol=tol,
-    )
-    if batched:
-        fn = jax.vmap(fn, in_axes=(None, None, 0))
-    return jax.jit(fn)
+    def ws_solve(lam, lengths, b):
+        return _solve_jax(
+            lam,
+            lengths,
+            b,
+            kind=kind,
+            n_quad=n_quad,
+            n_outer=n_outer,
+            n_bisect=n_bisect,
+            damping=damping,
+            tol=tol,
+        )
+
+    def ws_solve_batch(lam, lengths, b_batch):
+        return jax.vmap(ws_solve, in_axes=(None, None, 0))(lam, lengths, b_batch)
+
+    # The function's name is the executable's name in a device trace.
+    return jax.jit(ws_solve_batch if batched else ws_solve)
 
 
 def _check_inputs(lam, lengths, b, attribution):
@@ -287,33 +320,46 @@ def solve_workingset(
     ``b_i < sum_k l_k / J`` (checked). ``attribution`` picks L1 / Lstar /
     L2 / full.
     """
-    lam = np.asarray(lam, dtype=np.float64)
-    lengths = np.asarray(lengths, dtype=np.float64)
-    b = np.asarray(b, dtype=np.float64)
-    J, N = lam.shape
-    if b.shape != (J,):
-        raise ValueError("shape mismatch between lam, lengths, b")
-    _check_inputs(lam, lengths, b, attribution)
+    with spans.span("ws.prepare"):
+        lam = np.asarray(lam, dtype=np.float64)
+        lengths = np.asarray(lengths, dtype=np.float64)
+        b = np.asarray(b, dtype=np.float64)
+        J, N = lam.shape
+        if b.shape != (J,):
+            raise ValueError("shape mismatch between lam, lengths, b")
+        _check_inputs(lam, lengths, b, attribution)
 
-    if n_quad is None:
-        n_quad = max(8, (J + 1) // 2 + 1)
+        if n_quad is None:
+            n_quad = max(8, (J + 1) // 2 + 1)
 
-    dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    fn = _jitted_solver(
-        attribution, n_quad, n_outer, n_bisect, damping, tol, False
-    )
-    t, h, L, res, iters, delta = fn(
-        jnp.asarray(lam, dtype), jnp.asarray(lengths, dtype), jnp.asarray(b, dtype)
-    )
-    sol = WorkingSetSolution(
-        t=np.asarray(t, np.float64),
-        h=np.asarray(h, np.float64),
-        L=np.asarray(L, np.float64),
-        residual=np.asarray(res, np.float64),
-        iterations=int(iters),
-        converged=bool(delta <= tol),
-    )
-    return sol.with_rates(lam)
+        dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+        fn = _jitted_solver(
+            attribution, n_quad, n_outer, n_bisect, damping, tol, False
+        )
+        args = (
+            jnp.asarray(lam, dtype),
+            jnp.asarray(lengths, dtype),
+            jnp.asarray(b, dtype),
+        )
+    with spans.span("ws.solve"):
+        out = fn(*args)
+        if spans.recording():
+            jax.block_until_ready(out)
+    t, h, L, res, iters, delta, work = out
+    with spans.span("ws.collect"):
+        sol = WorkingSetSolution(
+            t=np.asarray(t, np.float64),
+            h=np.asarray(h, np.float64),
+            L=np.asarray(L, np.float64),
+            residual=np.asarray(res, np.float64),
+            iterations=int(iters),
+            converged=bool(delta <= tol),
+        )
+        if spans.recording():
+            evals = (sol.iterations + 1) * (GROW_STEPS + n_bisect)
+            spans.count("ws.residual_evals", evals)
+            spans.count("ws.residual_evals_useful", int(np.asarray(work).sum()))
+        return sol.with_rates(lam)
 
 
 def solve_workingset_unshared(lam, lengths, b, **kw) -> WorkingSetSolution:
@@ -389,7 +435,7 @@ def solve_workingset_batch(
 
     dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     fn = _jitted_solver(attribution, n_quad, n_outer, n_bisect, damping, tol, True)
-    t, h, L, res, iters, delta = fn(
+    t, h, L, res, iters, delta, _ = fn(
         jnp.asarray(lam, dtype),
         jnp.asarray(lengths, dtype),
         jnp.asarray(b_batch, dtype),

@@ -48,6 +48,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.admission import AdmissionController
 from repro.core.cluster import simulate_cluster
 from repro.core.fastsim import (
@@ -184,9 +185,10 @@ def _run_working_set(sc: Scenario) -> Report:
             "working_set estimator has no S-LRU model; use monte_carlo "
             "for variant='slru'"
         )
-    lam = _rates_for(sc)
-    _, length_seed = derive_seeds(sc.seed)
-    lengths = sc.workload.object_lengths(length_seed).astype(np.float64)
+    with spans.span("ws.prepare"):
+        lam = _rates_for(sc)
+        _, length_seed = derive_seeds(sc.seed)
+        lengths = sc.workload.object_lengths(length_seed).astype(np.float64)
     kw = dict(
         n_quad=est.n_quad,
         n_outer=est.n_outer,
@@ -223,26 +225,27 @@ def _run_working_set(sc: Scenario) -> Report:
         )
         hit_prob = sol.h
     elapsed = time.perf_counter() - t0
-    per_proxy, overall = _hit_rates(hit_prob, lam)
-    return Report(
-        scenario=sc.to_dict(),
-        estimator="working_set",
-        backend="jax-ws",
-        hit_prob=hit_prob,
-        hit_rate=per_proxy,
-        overall_hit_rate=overall,
-        n_requests=0,
-        warmup=0,
-        elapsed_s=elapsed,
-        throughput_rps=0.0,
-        converged=sol.converged,
-        extras={
-            "effective_attribution": attribution,
-            "characteristic_times": sol.t.tolist(),
-            "iterations": sol.iterations,
-            "max_abs_residual": float(np.max(np.abs(sol.residual))),
-        },
-    )
+    with spans.span("ws.collect"):
+        per_proxy, overall = _hit_rates(hit_prob, lam)
+        return Report(
+            scenario=sc.to_dict(),
+            estimator="working_set",
+            backend="jax-ws",
+            hit_prob=hit_prob,
+            hit_rate=per_proxy,
+            overall_hit_rate=overall,
+            n_requests=0,
+            warmup=0,
+            elapsed_s=elapsed,
+            throughput_rps=0.0,
+            converged=sol.converged,
+            extras={
+                "effective_attribution": attribution,
+                "characteristic_times": sol.t.tolist(),
+                "iterations": sol.iterations,
+                "max_abs_residual": float(np.max(np.abs(sol.residual))),
+            },
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +270,7 @@ def _run_monte_carlo(sc: Scenario) -> Report:
             sc, n, warmup, lengths, trace_seed, streaming
         )
     if system.backend == "reference":
-        trace = sc.workload.sample(n, trace_seed)
+        trace = _sample(sc, n, trace_seed)
         res = _run_reference(sc, trace, lengths, warmup)
         backend = "reference"
     elif streaming:
@@ -276,9 +279,7 @@ def _run_monte_carlo(sc: Scenario) -> Report:
         # to the one-shot dense path (tests/test_streaming.py).
         res = simulate_chunks(
             system.to_sim_params(),
-            sc.workload.iter_chunks(
-                n, trace_seed, chunk_size=sc.estimator.chunk_size
-            ),
+            _chunks(sc, n, trace_seed),
             sc.workload.n_objects,
             n,
             lengths=lengths,
@@ -289,7 +290,7 @@ def _run_monte_carlo(sc: Scenario) -> Report:
         )
         backend = res.engine
     else:
-        trace = sc.workload.sample(n, trace_seed)
+        trace = _sample(sc, n, trace_seed)
         res = simulate_trace(
             system.to_sim_params(),
             trace,
@@ -302,6 +303,27 @@ def _run_monte_carlo(sc: Scenario) -> Report:
         # SimResult records the backend that actually ran (under "auto"
         # the C path can silently fall back to the Python loop).
         backend = res.engine
+    with spans.span("mc.collect"):
+        return _monte_carlo_report(sc, res, backend, streaming)
+
+
+def _sample(sc: Scenario, n: int, seed: int) -> IRMTrace:
+    with spans.span("mc.trace"):
+        return sc.workload.sample(n, seed)
+
+
+def _chunks(sc: Scenario, n: int, seed: int):
+    """The streamed trace, each chunk drawn under its own span."""
+    return spans.each(
+        "mc.trace",
+        sc.workload.iter_chunks(n, seed, chunk_size=sc.estimator.chunk_size),
+    )
+
+
+def _monte_carlo_report(
+    sc: Scenario, res: SimResult, backend: str, streaming: bool
+) -> Report:
+    system = sc.system
     lam = _rates_for(sc)
     per_proxy, overall = _hit_rates(res.occupancy, lam)
     ripple = None
@@ -487,12 +509,9 @@ def _run_monte_carlo_ensemble(
         from repro.core import fastsim_jax
 
         if streaming:
-            traces = [
-                sc.workload.iter_chunks(n, s, chunk_size=est.chunk_size)
-                for s in seeds
-            ]
+            traces = [_chunks(sc, n, s) for s in seeds]
         else:
-            traces = [sc.workload.sample(n, s) for s in seeds]
+            traces = [_sample(sc, n, s) for s in seeds]
         results = fastsim_jax.simulate_ensemble(
             params,
             traces,
@@ -508,18 +527,14 @@ def _run_monte_carlo_ensemble(
         results = []
         for s in seeds:
             if system.backend == "reference":
-                res = _run_reference(
-                    sc, sc.workload.sample(n, s), lengths, warmup
-                )
+                res = _run_reference(sc, _sample(sc, n, s), lengths, warmup)
                 res.engine = "reference"
                 results.append(res)
             elif streaming:
                 results.append(
                     simulate_chunks(
                         params,
-                        sc.workload.iter_chunks(
-                            n, s, chunk_size=est.chunk_size
-                        ),
+                        _chunks(sc, n, s),
                         sc.workload.n_objects,
                         n,
                         lengths=lengths,
@@ -533,7 +548,7 @@ def _run_monte_carlo_ensemble(
                 results.append(
                     simulate_trace(
                         params,
-                        sc.workload.sample(n, s),
+                        _sample(sc, n, s),
                         sc.workload.n_objects,
                         lengths=lengths,
                         warmup=warmup,
@@ -541,7 +556,8 @@ def _run_monte_carlo_ensemble(
                         engine=system.backend,
                     )
                 )
-    return _ensemble_report(sc, results, streaming, batched)
+    with spans.span("mc.collect"):
+        return _ensemble_report(sc, results, streaming, batched)
 
 
 # Cap on the stacked (R, J, N) per-replica hit-probability payload kept

@@ -31,6 +31,8 @@ from dataclasses import dataclass, field, replace
 from pathlib import Path
 from typing import Optional
 
+from repro import spans
+
 from .report import Report
 from .system import Estimator, System
 from .workload import Workload
@@ -68,7 +70,8 @@ class Scenario:
         """Produce a Report with the configured estimator."""
         from .runner import run_scenario
 
-        return run_scenario(self)
+        with spans.span("scenario.run"):
+            return run_scenario(self)
 
     # ------------------------------------------------------------------
     def with_estimator(self, kind: str, **kw) -> "Scenario":
