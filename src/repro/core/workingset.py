@@ -247,6 +247,33 @@ def _solve_jax(
     return t, h, L, res, iters, delta, work
 
 
+def _pack(outputs) -> jnp.ndarray:
+    """``_solve_jax``'s outputs as one vector of its float dtype, so that
+    a solve crosses to the host in one transfer: ``t``, ``h``, ``L``,
+    ``res`` and ``delta`` as they are, then ``iters`` and ``work`` with
+    their bits moved by ``bitcast_convert_type``. :func:`_unpack`
+    splits it."""
+    t, h, L, res, iters, delta, work = outputs
+    ints = jnp.concatenate([jnp.reshape(iters, (1,)), work])
+    ints = ints.astype(f"int{8 * t.dtype.itemsize}")
+    return jnp.concatenate(
+        [t, h.ravel(), L.ravel(), res, jnp.reshape(delta, (1,)),
+         jax.lax.bitcast_convert_type(ints, t.dtype)]
+    )
+
+
+def _unpack(vec: np.ndarray, J: int, N: int) -> tuple:
+    """``t, h, L, res, iters, delta, work`` from :func:`_pack`'s vector,
+    over any leading (batch) axes."""
+    lead = vec.shape[:-1]
+    t, h, L, res, delta, ints = np.split(
+        vec, np.cumsum([J, J * N, J * N, J, 1]), axis=-1
+    )
+    ints = ints.view(f"i{vec.itemsize}")
+    return (t, h.reshape(lead + (J, N)), L.reshape(lead + (J, N)), res,
+            ints[..., 0], delta[..., 0], ints[..., 1:])
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted_solver(
     kind: str,
@@ -264,19 +291,22 @@ def _jitted_solver(
     compilations for 8 identical-shape solves. The cache reuses the
     executable; ``batched=True`` additionally ``vmap``s over a batch of
     allocation vectors so a whole ``b``-grid is one compiled call. The
-    executables are named ``jit_ws_solve`` and ``jit_ws_solve_batch``.
+    executables are named ``jit_ws_solve`` and ``jit_ws_solve_batch``,
+    and return :func:`_pack`'s vector (one per allocation vector).
     """
     def ws_solve(lam, lengths, b):
-        return _solve_jax(
-            lam,
-            lengths,
-            b,
-            kind=kind,
-            n_quad=n_quad,
-            n_outer=n_outer,
-            n_bisect=n_bisect,
-            damping=damping,
-            tol=tol,
+        return _pack(
+            _solve_jax(
+                lam,
+                lengths,
+                b,
+                kind=kind,
+                n_quad=n_quad,
+                n_outer=n_outer,
+                n_bisect=n_bisect,
+                damping=damping,
+                tol=tol,
+            )
         )
 
     def ws_solve_batch(lam, lengths, b_batch):
@@ -299,6 +329,21 @@ def _check_inputs(lam, lengths, b, attribution):
         )
     if attribution == "full" and np.any(b >= lengths.sum()):
         raise ValueError("b_i >= total catalogue size: cache never evicts")
+
+
+def _fetch(packed, J: int, N: int) -> tuple:
+    """The solver's outputs as numpy arrays, from :func:`_pack`'s vector
+    in one device-to-host fetch (counted as ``ws.fetches``)."""
+    host = np.asarray(packed)
+    spans.count("ws.fetches", 1)
+    return _unpack(host, J, N)
+
+
+def _converged(delta, tol):
+    """The solver's stop test ``delta <= tol`` on the host: ``tol`` in
+    ``delta``'s own dtype, as the device compares it (bit for bit)."""
+    delta = np.asarray(delta)
+    return delta <= np.asarray(tol, delta.dtype)
 
 
 def solve_workingset(
@@ -345,20 +390,20 @@ def solve_workingset(
         out = fn(*args)
         if spans.recording():
             jax.block_until_ready(out)
-    t, h, L, res, iters, delta, work = out
     with spans.span("ws.collect"):
+        t, h, L, res, iters, delta, work = _fetch(out, J, N)
         sol = WorkingSetSolution(
             t=np.asarray(t, np.float64),
             h=np.asarray(h, np.float64),
             L=np.asarray(L, np.float64),
             residual=np.asarray(res, np.float64),
             iterations=int(iters),
-            converged=bool(delta <= tol),
+            converged=bool(_converged(delta, tol)),
         )
         if spans.recording():
             evals = (sol.iterations + 1) * (GROW_STEPS + n_bisect)
             spans.count("ws.residual_evals", evals)
-            spans.count("ws.residual_evals_useful", int(np.asarray(work).sum()))
+            spans.count("ws.residual_evals_useful", int(work.sum()))
         return sol.with_rates(lam)
 
 
@@ -435,13 +480,17 @@ def solve_workingset_batch(
 
     dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     fn = _jitted_solver(attribution, n_quad, n_outer, n_bisect, damping, tol, True)
-    t, h, L, res, iters, delta, _ = fn(
-        jnp.asarray(lam, dtype),
-        jnp.asarray(lengths, dtype),
-        jnp.asarray(b_batch, dtype),
+    t, h, L, res, iters, delta, _ = _fetch(
+        fn(
+            jnp.asarray(lam, dtype),
+            jnp.asarray(lengths, dtype),
+            jnp.asarray(b_batch, dtype),
+        ),
+        J,
+        N,
     )
     t, h, L, res = (np.asarray(x, np.float64) for x in (t, h, L, res))
-    iters, delta = np.asarray(iters), np.asarray(delta)
+    converged = _converged(delta, tol)
     out = []
     for k in range(b_batch.shape[0]):
         sol = WorkingSetSolution(
@@ -450,7 +499,7 @@ def solve_workingset_batch(
             L=L[k],
             residual=res[k],
             iterations=int(iters[k]),
-            converged=bool(delta[k] <= tol),
+            converged=bool(converged[k]),
         )
         out.append(sol.with_rates(lam))
     return out

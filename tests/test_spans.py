@@ -18,7 +18,7 @@ from repro.scenario import Estimator, Scenario, System, Workload
 
 MC_SPANS = {"mc.trace", "mc.feed", "mc.compile", "mc.drive", "mc.collect"}
 WS_SPANS = {"ws.prepare", "ws.solve", "ws.collect"}
-WS_COUNTERS = {"ws.residual_evals", "ws.residual_evals_useful"}
+WS_COUNTERS = {"ws.residual_evals", "ws.residual_evals_useful", "ws.fetches"}
 
 
 @pytest.fixture
@@ -95,6 +95,18 @@ def test_residual_evaluation_counters(ring, tmp_path):
     evals = (rep.extras["iterations"] + 1) * (workingset.GROW_STEPS + est.n_bisect)
     assert counts["ws.residual_evals"] == evals
     assert 1 <= counts["ws.residual_evals_useful"] <= evals
+
+
+def test_one_device_to_host_fetch_per_solve(ring, tmp_path):
+    sc = scenarios()["ws"]
+    traced(tmp_path, sc, sc)
+    events = spans.events()
+    fetches = [e for e in events if e.name == "ws.fetches"]
+    assert [e.value for e in fetches] == [1, 1]
+    parents = {e.span_id: e.name for e in events if e.value is None}
+    assert all(parents[e.parent_id] == "ws.collect" for e in fetches)
+    sc.run()  # recording off: nothing more is counted
+    assert spans.events() == events
 
 
 def test_nothing_is_recorded_without_a_profiler_session(ring):

@@ -5,6 +5,9 @@ the numpy reference implementation ``_numpy_residual``) stays importable
 and the deterministic tests run without it.
 """
 
+import functools
+import itertools
+
 import numpy as np
 import pytest
 
@@ -13,6 +16,7 @@ try:
 except ImportError:  # pragma: no cover - optional dependency
     given = settings = st = None
 
+from repro.core import workingset
 from repro.core import (
     attribution_matrix,
     expected_inverse_one_plus,
@@ -22,6 +26,7 @@ from repro.core import (
     solve_workingset_unshared,
 )
 
+import jax
 import jax.numpy as jnp
 
 
@@ -126,6 +131,56 @@ def test_batch_solver_matches_sequential():
         seq = solve_workingset(lam, lengths, b, attribution="L1")
         assert np.allclose(sol.h, seq.h, atol=5e-5)
         assert np.max(np.abs(sol.residual)) < 1e-2 * b.max()
+
+
+TABLE2_N = 1000
+TABLE2_ALPHAS = (0.75, 0.5, 1.0)
+FIG2_ALPHAS = tuple(0.5 + 0.5 * i for i in range(9))
+FIG2_N_REDUCED = 5000  # the paper's 10^6 objects, cut 200-fold with b
+
+
+@pytest.mark.parametrize(
+    "alphas, n, b",
+    [(TABLE2_ALPHAS, TABLE2_N, b) for b in itertools.product((8.0, 64.0), repeat=3)]
+    + [(FIG2_ALPHAS, FIG2_N_REDUCED, (5.0,) * 3 + (10.0,) * 3 + (35.0,) * 3)],
+)
+def test_one_fetch_matches_the_outputs_one_by_one(alphas, n, b):
+    """The packed single fetch and the host-side stop test give the
+    solution the solver's outputs give when each is taken to the host
+    alone."""
+    lam = rate_matrix(n, list(alphas))
+    lengths = np.ones(n)
+    b = np.asarray(b)
+    sol = solve_workingset(lam, lengths, b, attribution="L1")
+    tol, n_quad = 1e-7, max(8, (len(alphas) + 1) // 2 + 1)
+    fn = jax.jit(
+        functools.partial(
+            workingset._solve_jax, kind="L1", n_quad=n_quad, n_outer=200,
+            n_bisect=90, damping=0.7, tol=tol,
+        )
+    )
+    dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    t, h, L, res, iters, delta, _ = fn(
+        *(jnp.asarray(x, dtype) for x in (lam, lengths, b))
+    )
+    for got, want in ((sol.t, t), (sol.h, h), (sol.L, L), (sol.residual, res)):
+        assert got.dtype == np.float64
+        assert np.array_equal(got, np.asarray(want, np.float64))
+    assert sol.iterations == int(iters)
+    assert sol.converged is bool(delta <= tol)
+
+
+@pytest.mark.parametrize("step", [-1, 0, 1])
+def test_host_stop_test_matches_the_device_comparison(step):
+    """At float32(tol) and at its two float32 neighbours."""
+    tol = 1e-7
+    delta = np.float32(tol)
+    for _ in range(abs(step)):
+        delta = np.nextafter(delta, np.float32(step * np.inf), dtype=np.float32)
+    assert delta.dtype == np.float32
+    want = bool(jnp.asarray(delta) <= tol)
+    assert bool(workingset._converged(delta, tol)) is want
+    assert want is (step <= 0)
 
 
 def _solver_residuals_random(J, alpha0, seed):
