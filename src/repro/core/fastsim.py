@@ -52,7 +52,7 @@ feeds the request stream chunk by chunk (``Workload.iter_chunks`` /
 across chunks in every backend — the trace is never materialized — and
 returns occupancy as a :class:`SparseOccupancy` (indices, values) pair.
 Chunked + sparse runs are bit-identical to one-shot dense runs
-(``tests/test_streaming.py``); the XLA driver carries dense int32 state
+(``tests/test_streaming.py``); the XLA driver carries dense integer state
 between chunks (fixed-shape buffers) but produces the same outputs.
 
 Which engine to use
@@ -1007,7 +1007,7 @@ def make_chunk_driver(
     Returns ``(driver, engine_name, vlen_scale)``; the driver honours the
     ``feed(proxies, objects)`` / ``finish(n_total)`` protocol with state
     resident between feeds, and ``n_requests`` (total stream length, when
-    known up front) only gates the int32-envelope check of the XLA
+    known up front) only gates the exactness-envelope check of the XLA
     backend.
     """
     N = int(n_objects)
@@ -1050,7 +1050,7 @@ def make_chunk_driver(
             ):
                 raise RuntimeError(
                     "engine='xla' requested but the XLA driver is not applicable "
-                    "(batch_interval > 0, or int32 range exceeded)"
+                    "(batch_interval > 0, or sizes past its exact integer range)"
                 )
             try:
                 driver = _make_xla(params, N, lengths_a, warmup, ripple_from, scale)
@@ -1146,12 +1146,32 @@ def _make_xla(params, n_objects, lengths, warmup, ripple_from, scale):
         )
 
 
+def vlen_bound(J: int, max_length: int, max_bhat: int) -> int:
+    """A bound on every scaled virtual length the drive loops hold.
+
+    After each request list ``j`` holds at most ``b_hat_j * M`` (``M =
+    lcm(1..J)``); each of its objects is charged at least ``l / J``, so
+    its objects' full lengths sum to at most ``J * b_hat_j``. Within a
+    request the list can gain one object and its charges can grow to
+    full lengths, no further: ``(J * max(b_hat) + max(l)) * M``.
+    """
+    return (J * int(max_bhat) + int(max_length)) * _lcm_1_to(J)
+
+
 def _xla_applicable(
     n: int, n_objects: int, lengths: np.ndarray, params: SimParams
 ) -> bool:
-    """int32-exactness envelope of the compiled driver."""
+    """Exactness envelope of the compiled drivers.
+
+    Indices, counters, lengths and the physical occupancy are int32:
+    ``n < 2**31``, ``J * N < 2**31``, and the physical occupancy, at
+    most ``max(B, sum b_hat)`` plus one object in flight, below 2**31.
+    Virtual lengths are int32 or int64, whichever holds
+    :func:`vlen_bound` (``fastsim_jax.vlen_dtype``), so byte-sized
+    objects fit: at J=9, lengths up to 2**20 and budgets up to
+    ``B < 2**31`` bound them by ~5e13, far inside int64.
+    """
     J = len(params.allocations)
-    scale = _lcm_1_to(J)
     # vlen is bounded by the *ripple* allocation (plus one transient
     # attach), so b_hat — not b — sets the envelope.
     b_hat = (
@@ -1159,11 +1179,17 @@ def _xla_applicable(
         if params.ripple_allocations is not None
         else params.allocations
     )
+    B = (
+        params.physical_capacity
+        if params.physical_capacity is not None
+        else sum(params.allocations)
+    )
+    max_len = int(np.max(lengths))
     return (
         n < 2**31
         and J * n_objects < 2**31
-        and int(np.max(lengths)) * scale * (J + 1) < 2**31
-        and max(b_hat, default=0) * scale < 2**30
+        and max(int(B), sum(int(x) for x in b_hat)) + max_len < 2**31
+        and vlen_bound(J, max_len, max(b_hat, default=0)) < 2**63
     )
 
 

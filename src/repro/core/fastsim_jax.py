@@ -48,13 +48,22 @@ Compilation is always performed *outside* the timed region: each new
 resulting executable is reused for every subsequent same-shape ``feed``,
 so ``elapsed`` provably excludes compile time.
 
-All arithmetic is int32 (exact): requires ``n_requests < 2**31`` and
-``max_length * lcm(1..J) * J < 2**31`` — both hold with orders of
-magnitude to spare at the paper's Section VI-C scale. Equivalence with
-the pure-Python engines (and hence with the reference spec) is asserted
-by ``tests/test_fastsim.py`` / ``tests/test_streaming.py`` /
-``tests/test_ensemble.py`` as exact equality of occupancy integers,
-counters, virtual lengths, and ripple histograms.
+All arithmetic is integer (exact). Indices, counters, object lengths
+and the physical occupancy are int32: ``n_requests < 2**31``, ``J * N <
+2**31`` and ``max(B, sum b_hat) + max_length < 2**31``. The virtual list
+lengths, the thresholds ``b * M`` and ``b_hat * M`` and each holder's
+charge ``l * (M // |P|)`` (``M = lcm(1..J)``) are bounded by
+``fastsim.vlen_bound`` = ``(J * max(b_hat) + max_length) * M``: where
+that is below 2**31 they are int32, else int64, built and traced with
+64-bit types enabled for the drive loop alone (:func:`vlen_dtype`). Unit
+objects at the paper's Section VI-C scale stay int32 (bound ~1.6e8 at
+J=9); byte-sized memcached items (lengths up to 2**20, ``B < 2**31``)
+need int64 (bound ~5e13 at J=9). Equivalence with the pure-Python
+engines (and hence with the reference spec) is asserted by
+``tests/test_fastsim.py`` / ``tests/test_streaming.py`` /
+``tests/test_ensemble.py`` / ``tests/test_byte_lengths.py`` as exact
+equality of occupancy integers, counters, virtual lengths, and ripple
+histograms.
 
 Supports the flat shared-LRU variant with ghost retention on/off and RRE
 slack thresholds (``b_hat``); the S-LRU, not-shared, and delayed-batch
@@ -63,6 +72,7 @@ variants run on the pure-Python loops (see ``fastsim.simulate_trace``).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Dict, List, Optional, Sequence
 
@@ -74,7 +84,7 @@ from jax import lax
 
 from repro import spans
 
-from .fastsim import HIST_BUCKETS
+from .fastsim import HIST_BUCKETS, vlen_bound
 
 # Evictions-per-set histogram buckets — the single shared constant from
 # fastsim (all backends clamp into the same last bucket, keeping
@@ -89,13 +99,33 @@ def _upd(vec, idx, val, pred):
     return vec.at[safe].set(jnp.where(pred, val, vec[safe]))
 
 
+def vlen_dtype(J: int, max_length: int, max_bhat: int):
+    """The virtual lengths' integer type: int32 where
+    :func:`~repro.core.fastsim.vlen_bound` fits it, else int64 (None
+    past int64)."""
+    bound = vlen_bound(J, max_length, max_bhat)
+    if bound < 2**31:
+        return jnp.int32
+    return jnp.int64 if bound < 2**63 else None
+
+
+def _wide(vdt):
+    """The context that builds the state and constants of a driver whose
+    virtual lengths are ``vdt``, and traces it: 64-bit types on for
+    int64, nothing otherwise. The compiled executable runs outside it."""
+    if vdt == jnp.int64:
+        return jax.enable_x64(True)
+    return contextlib.nullcontext()
+
+
 def _init_state(
-    J: int, N: int, batch: Optional[int] = None
+    J: int, N: int, batch: Optional[int] = None, vdt=jnp.int32
 ) -> Dict[str, jnp.ndarray]:
     """Fresh carried state for :func:`_drive` (one cold engine).
 
     ``batch=R`` prepends a replica axis to every leaf — R independent
-    cold engines for the vmapped ensemble driver.
+    cold engines for the vmapped ensemble driver. ``vlen`` is ``vdt``
+    (build int64 state under :func:`_wide`), every other leaf int32.
     """
     I32 = jnp.int32
     pre = () if batch is None else (int(batch),)
@@ -114,7 +144,7 @@ def _init_state(
         "hold": full((J * N,), 0),
         "hcnt": full((N,), 0),
         "length": full((N,), 0),
-        "vlen": full((J,), 0),
+        "vlen": jnp.zeros(pre + (J,), vdt),
         "phys": scalar(0),
         "gnxt": full((N,), -1),
         "gprv": full((N,), -1),
@@ -142,9 +172,9 @@ def _drive_impl(
     O,  # (n,) int32 objects of this chunk
     idx0,  # () int32 absolute index of the chunk's first request
     lengths,  # (N,) int32
-    b_scaled,  # (J,) int32
-    bhat_scaled,  # (J,) int32
-    share_arr,  # (J+2,) int32: [0, M//1, ..., M//J, 0]
+    b_scaled,  # (J,) int32 or int64: the virtual lengths' type
+    bhat_scaled,  # (J,) same type
+    share_arr,  # (J+2,) same type: [0, M//1, ..., M//J, 0]
     B,  # () int32
     warmup,  # () int32
     ripple_from,  # () int32
@@ -208,7 +238,8 @@ def _drive_impl(
 
     def step(local, st):
         st = dict(st)
-        idx = idx0 + jnp.int32(local)
+        local = jnp.int32(local)  # the loop's counter is int64 under x64
+        idx = idx0 + local
         i = P[local]
         k = O[local]
         base = i * N
@@ -223,10 +254,10 @@ def _drive_impl(
         miss = (~held) & (~resident)
         att = ~held  # both cache-hit and miss attach k to list i
 
-        st["n_hit_list"] = st["n_hit_list"] + jnp.where(hit, 1, 0)
-        st["n_hit_cache"] = st["n_hit_cache"] + jnp.where(hitc, 1, 0)
-        st["n_miss"] = st["n_miss"] + jnp.where(miss, 1, 0)
-        st["hits_p"] = st["hits_p"].at[i].add(jnp.where(hit & post, 1, 0))
+        st["n_hit_list"] = st["n_hit_list"] + hit.astype(I32)
+        st["n_hit_cache"] = st["n_hit_cache"] + hitc.astype(I32)
+        st["n_miss"] = st["n_miss"] + miss.astype(I32)
+        st["hits_p"] = st["hits_p"].at[i].add((hit & post).astype(I32))
 
         # ---- miss: make physical room among ghosts, become resident --
         l_new = lengths[k]
@@ -253,7 +284,7 @@ def _drive_impl(
         st["gprv"] = _upd(st["gprv"], gn, gp, res & (gn != -1))
         st["isghost"] = _upd(st["isghost"], k, 0, res)
         st["hold"] = _upd(st["hold"], ik, 1, att)
-        st["hcnt"] = st["hcnt"].at[k].add(jnp.where(att, 1, 0))
+        st["hcnt"] = st["hcnt"].at[k].add(att.astype(I32))
 
         # ---- list hit: unlink k from its current position ------------
         not_head = st["head"][i] != k
@@ -331,7 +362,7 @@ def _drive_impl(
             else:
                 s["phys"] = s["phys"] - jnp.where(cons, vl, 0)
                 s["length"] = _upd(s["length"], v, 0, cons)
-            return s, n_ev + 1, n_rip + jnp.where(worst != i, 1, 0)
+            return s, n_ev + 1, n_rip + (worst != i).astype(I32)
 
         sub = {key: st[key] for key in EV_KEYS}
         sub, n_ev, n_rip = lax.while_loop(
@@ -342,12 +373,12 @@ def _drive_impl(
         # ---- miss: reconcile transient physical overshoot ------------
         st = ghost_loop(st, lambda phys: miss & (phys > B))
         rec = miss & (idx >= ripple_from)
-        one = jnp.where(rec, 1, 0)
+        one = rec.astype(I32)
         st["n_sets"] = st["n_sets"] + one
         st["hist"] = st["hist"].at[jnp.minimum(n_ev, HIST_MAX - 1)].add(one)
         st["n_rip"] = st["n_rip"] + jnp.where(rec, n_rip, 0)
         st["n_prim"] = st["n_prim"] + jnp.where(rec, n_ev - n_rip, 0)
-        st["reqs_p"] = st["reqs_p"].at[i].add(jnp.where(post, 1, 0))
+        st["reqs_p"] = st["reqs_p"].at[i].add(post.astype(I32))
         return st
 
     return lax.fori_loop(0, n, step, st)
@@ -372,9 +403,9 @@ def _drive_batched_impl(
     O,  # (R, n) int32 objects
     idx0,  # () int32 absolute index of the chunk's first request
     lengths,  # (N,) int32 (shared across lanes)
-    b_scaled,  # (J,) or (R, J) int32 — per-lane rows = stacked sweep points
-    bhat_scaled,  # (J,) or (R, J) int32
-    share_arr,  # (J+2,) int32
+    b_scaled,  # (J,) or (R, J) vlen type — per-lane rows = sweep points
+    bhat_scaled,  # (J,) or (R, J) vlen type
+    share_arr,  # (J+2,) vlen type
     B,  # () int32
     warmup,  # () int32
     ripple_from,  # () int32
@@ -508,9 +539,9 @@ def _drive_batched_impl(
         missnow = doreq & missp
         att = doreq & (~held)
 
-        st["n_hit_list"] = st["n_hit_list"] + jnp.where(hit, 1, 0)
-        st["n_hit_cache"] = st["n_hit_cache"] + jnp.where(hitc, 1, 0)
-        st["n_miss"] = st["n_miss"] + jnp.where(missnow, 1, 0)
+        st["n_hit_list"] = st["n_hit_list"] + hit.astype(I32)
+        st["n_hit_cache"] = st["n_hit_cache"] + hitc.astype(I32)
+        st["n_miss"] = st["n_miss"] + missnow.astype(I32)
         st["hits_p"] = aJ(st["hits_p"], i, ones_l, hit & post)
 
         # miss: become resident (room was made above / in prior rounds)
@@ -555,7 +586,7 @@ def _drive_batched_impl(
         st["res_since"] = s1(st["res_since"], ik, idx, att)
 
         # request-boundary resets of the per-request registers
-        wasmiss = jnp.where(doreq, jnp.where(missnow, 1, 0), wasmiss)
+        wasmiss = jnp.where(doreq, missnow.astype(I32), wasmiss)
         nev = jnp.where(doreq, 0, nev)
         nrip = jnp.where(doreq, 0, nrip)
 
@@ -599,8 +630,8 @@ def _drive_batched_impl(
         else:
             st["phys"] = st["phys"] - jnp.where(cons, vl, 0)
             st["length"] = s1(st["length"], v, 0, cons)
-        nev = nev + jnp.where(evact, 1, 0)
-        nrip = nrip + jnp.where(evact & (worst != i), 1, 0)
+        nev = nev + evact.astype(I32)
+        nrip = nrip + (evact & (worst != i)).astype(I32)
 
         # ---- transitions + request completion ------------------------
         over2 = jnp.max(st["vlen"] - lim, axis=1) > 0
@@ -614,12 +645,12 @@ def _drive_batched_impl(
         )
         done = past_ev & ~rec_need
         recs = done & (wasmiss == 1) & (idx >= ripple_from)
-        st["n_sets"] = st["n_sets"] + jnp.where(recs, 1, 0)
+        st["n_sets"] = st["n_sets"] + recs.astype(I32)
         st["hist"] = a1(st["hist"], jnp.minimum(nev, HIST_MAX - 1), 1, recs)
         st["n_rip"] = st["n_rip"] + jnp.where(recs, nrip, 0)
         st["n_prim"] = st["n_prim"] + jnp.where(recs, nev - nrip, 0)
         st["reqs_p"] = aJ(st["reqs_p"], i, ones_l, done & post)
-        cur = cur + jnp.where(done, 1, 0)
+        cur = cur + done.astype(I32)
         phase = jnp.where(
             done,
             0,
@@ -681,35 +712,46 @@ class _RunnerBase:
         warmup: int,
         ripple_from: int,
         scale: int,
+        b=None,
+        b_hat=None,
     ) -> None:
+        """``b`` / ``b_hat`` override the allocations with ``(J,)`` or
+        per-lane ``(R, J)`` rows (raw units)."""
         J = len(params.allocations)
         self.J = J
         self.N = int(n_objects)
-        b = [int(x) for x in params.allocations]
-        b_hat = (
-            [int(x) for x in params.ripple_allocations]
-            if params.ripple_allocations is not None
-            else list(b)
-        )
+        if b is None:
+            b = params.allocations
+        if b_hat is None:
+            b_hat = (
+                params.ripple_allocations
+                if params.ripple_allocations is not None
+                else b
+            )
+        b = np.asarray(b, dtype=np.int64)
+        b_hat = np.asarray(b_hat, dtype=np.int64)
         B = (
             params.physical_capacity
             if params.physical_capacity is not None
-            else sum(b)
+            else int(np.asarray(params.allocations).sum())
         )
+        lengths = np.asarray(lengths)
+        self.vdt = vlen_dtype(J, int(lengths.max()), int(b_hat.max()))
+        if self.vdt is None:
+            raise ValueError("virtual lengths past int64: J or sizes too large")
         share = [0] + [scale // p for p in range(1, J + 1)] + [0]
         self.ghost_retention = bool(params.ghost_retention)
         self.warmup = int(warmup)
-        self.b_scaled = jnp.asarray([x * scale for x in b], jnp.int32)
-        self.bhat_scaled = jnp.asarray([x * scale for x in b_hat], jnp.int32)
-        self.consts = (
-            jnp.asarray(np.asarray(lengths), jnp.int32),
-            self.b_scaled,
-            self.bhat_scaled,
-            jnp.asarray(share, jnp.int32),
-            jnp.int32(B),
-            jnp.int32(warmup),
-            jnp.int32(ripple_from),
-        )
+        with _wide(self.vdt):
+            self.consts = (
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(b * scale, self.vdt),
+                jnp.asarray(b_hat * scale, self.vdt),
+                jnp.asarray(share, self.vdt),
+                jnp.int32(B),
+                jnp.int32(warmup),
+                jnp.int32(ripple_from),
+            )
         self._compiled: Dict[int, object] = {}
         self.n_compiles = 0
         self.idx = 0
@@ -737,7 +779,7 @@ class _RunnerBase:
             self.ghost_retention,
             self.N,
             self.J,
-            tuple(tuple(c.shape) for c in self.consts),
+            tuple((tuple(c.shape), c.dtype.name) for c in self.consts),
             m,
         ) + self._key_extra()
 
@@ -755,7 +797,7 @@ class _RunnerBase:
                 # the executable for every later same-shape feed — the
                 # warm-up is the real compiled object, not a hint to a
                 # version-dependent jit cache.
-                with spans.span("mc.compile"):
+                with spans.span("mc.compile"), _wide(self.vdt):
                     ex = self._fn().lower(*args).compile()
                 _AOT_CACHE[key] = ex
                 self.n_compiles += 1
@@ -826,7 +868,8 @@ class XLAChunkRunner(_RunnerBase):
         scale: int,
     ) -> None:
         super().__init__(params, n_objects, lengths, warmup, ripple_from, scale)
-        self.st = _init_state(self.J, self.N)
+        with _wide(self.vdt):
+            self.st = _init_state(self.J, self.N, vdt=self.vdt)
 
     def _fn(self):
         return _single_fn(self.ghost_retention, self.N)
@@ -850,7 +893,7 @@ class BatchedXLARunner(_RunnerBase):
     micro-op loop (lanes progress through their traces at their own
     pace; see :func:`_drive_batched_impl`). Lane 0 is bit-identical to
     :class:`XLAChunkRunner` fed the same trace (same per-lane update
-    sequence, same int32 arithmetic). With ``b_sweep`` / ``bhat_sweep``
+    sequence, same exact integer arithmetic). With ``b_sweep`` / ``bhat_sweep``
     each lane additionally gets its own eviction thresholds (stacked
     ``(b, b_hat)`` sweep points).
 
@@ -871,10 +914,10 @@ class BatchedXLARunner(_RunnerBase):
         b_sweep=None,
         bhat_sweep=None,
     ) -> None:
-        super().__init__(params, n_objects, lengths, warmup, ripple_from, scale)
         if replications < 1:
             raise ValueError("replications must be >= 1")
         self.R = int(replications)
+        J = len(params.allocations)
         self.sweep = b_sweep is not None or bhat_sweep is not None
         if self.sweep:
             # Per-lane (b, b_hat) sweep points, in raw allocation units.
@@ -894,18 +937,19 @@ class BatchedXLARunner(_RunnerBase):
                 if bhat_sweep is None
                 else np.asarray(bhat_sweep, dtype=np.int64)
             )
-            if b_sweep.shape != (self.R, self.J) or bhat_sweep.shape != (
+            if b_sweep.shape != (self.R, J) or bhat_sweep.shape != (
                 self.R,
-                self.J,
+                J,
             ):
                 raise ValueError("sweep arrays must have shape (R, J)")
             if np.any(bhat_sweep < b_sweep):
                 raise ValueError("sweep points must satisfy b_hat >= b")
-            consts = list(self.consts)
-            consts[1] = jnp.asarray(b_sweep * scale, jnp.int32)
-            consts[2] = jnp.asarray(bhat_sweep * scale, jnp.int32)
-            self.consts = tuple(consts)
-        self.st = _init_state(self.J, self.N, batch=self.R)
+        super().__init__(
+            params, n_objects, lengths, warmup, ripple_from, scale,
+            b=b_sweep, b_hat=bhat_sweep,
+        )
+        with _wide(self.vdt):
+            self.st = _init_state(self.J, self.N, batch=self.R, vdt=self.vdt)
 
     def _key_extra(self) -> tuple:
         return (self.R,)
@@ -996,7 +1040,7 @@ def simulate_ensemble(
         ripple_from = warmup
     if not _xla_applicable(n, N, lengths_a, params):
         raise ValueError(
-            "workload exceeds the XLA driver's int32-exactness envelope"
+            "workload exceeds the XLA driver's exact integer range"
         )
     J = len(params.allocations)
     scale = _lcm_1_to(J)

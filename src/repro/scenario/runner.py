@@ -341,6 +341,7 @@ def _monte_carlo_report(
             "frac_multi_eviction": float(res.frac_multi_eviction),
             "mean_evictions": float(res.mean_evictions),
         }
+        _count_evictions(ripple)
     return Report(
         scenario=sc.to_dict(),
         estimator="monte_carlo",
@@ -366,6 +367,17 @@ def _monte_carlo_report(
                 else {}
             ),
         },
+    )
+
+
+def _count_evictions(ripple: dict) -> None:
+    """A Report's eviction work, from the drive loop's histogram state:
+    counters ``mc.evictions`` (objects evicted by recorded sets) and
+    ``mc.sets`` (recorded sets that evicted at least one)."""
+    spans.count("mc.evictions", ripple["n_primary"] + ripple["n_ripple"])
+    spans.count(
+        "mc.sets",
+        ripple["n_sets_recorded"] - ripple["evictions_per_set"].get("0", 0),
     )
 
 
@@ -633,6 +645,7 @@ def _ensemble_report(
                 (ks * hist).sum() / n_sets if n_sets else 0.0
             ),
         }
+        _count_evictions(ripple)
 
     # Batched replicas share one wall clock; sequential replicas add up.
     elapsed = (

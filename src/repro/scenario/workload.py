@@ -35,8 +35,9 @@ object-size population that drive an experiment:
   layout in ``Report.extras["serving"]``.
 
 Object lengths come from a :class:`LengthSpec` (unit, fixed, Zipf-ranked,
-lognormal, or explicit), sampled deterministically from the scenario
-seed. Everything is JSON-serializable via ``to_dict``/``from_dict``.
+lognormal, key-plus-value memcached items, or explicit), sampled
+deterministically from the scenario seed. Everything is
+JSON-serializable via ``to_dict``/``from_dict``.
 """
 
 from __future__ import annotations
@@ -60,7 +61,7 @@ from repro.serving.trace import (
     serving_rates,
 )
 
-LENGTH_KINDS = ("unit", "fixed", "zipf", "lognormal", "explicit")
+LENGTH_KINDS = ("unit", "fixed", "zipf", "lognormal", "item", "explicit")
 WORKLOAD_KINDS = ("irm", "shot_noise", "trace", "tenant_churn", "serving")
 TENANT_ACTIONS = ("arrive", "depart")
 
@@ -76,6 +77,15 @@ class LengthSpec:
       objects big — the adversarial case for sharing).
     * ``lognormal`` — i.i.d. ``round(exp(N(mu, sigma)))`` clipped to
       ``[1, max_len]``, seeded from the scenario seed.
+    * ``item`` — memcached item bytes, key plus value:
+      ``round(key) + round(value)`` clipped to ``[1, max_len]``, the key
+      drawn from a generalized extreme value law ``key_gev = (mu, sigma,
+      xi)`` and the value from a generalized Pareto law ``value_gpd =
+      (mu, sigma, xi)``, each by its quantile function at uniforms ``u``
+      (keys, the first N) and ``u'`` (values, the next N) of the seeded
+      generator ``lognormal`` uses. The defaults are the fits of
+      Facebook's ETC pool (Atikoglu et al., SIGMETRICS 2012) with a
+      15-byte least value, as mutilate draws them.
     * ``explicit`` — ``values`` gives one length per object.
     """
 
@@ -85,6 +95,8 @@ class LengthSpec:
     max_len: int = 8
     mu: float = 0.0
     sigma: float = 0.5
+    key_gev: Tuple[float, float, float] = (30.7984, 8.20449, 0.078688)
+    value_gpd: Tuple[float, float, float] = (15.0, 214.476, 0.348238)
     values: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self) -> None:
@@ -94,6 +106,11 @@ class LengthSpec:
             )
         if self.kind == "explicit" and not self.values:
             raise ValueError("explicit length spec needs values")
+        for name in ("key_gev", "value_gpd"):
+            law = tuple(float(x) for x in getattr(self, name))
+            if len(law) != 3 or law[1] <= 0 or law[2] == 0:
+                raise ValueError(f"{name} is (mu, sigma > 0, xi != 0)")
+            object.__setattr__(self, name, law)
 
     def materialize(self, n_objects: int, seed: int) -> np.ndarray:
         """(N,) positive int64 lengths, deterministic in (spec, seed)."""
@@ -110,6 +127,16 @@ class LengthSpec:
         if self.kind == "lognormal":
             rng = np.random.default_rng(seed ^ 0x5EED1E)
             l = np.round(np.exp(rng.normal(self.mu, self.sigma, n_objects)))
+            return np.clip(l, 1, self.max_len).astype(np.int64)
+        if self.kind == "item":
+            rng = np.random.default_rng(seed ^ 0x5EED1E)
+            u = rng.random(2 * n_objects)
+            mk, sk, xk = self.key_gev
+            mv, sv, xv = self.value_gpd
+            with np.errstate(divide="ignore"):  # u = 0: the key's least
+                key = mk + sk * ((-np.log(u[:n_objects])) ** -xk - 1) / xk
+            value = mv + sv * ((1 - u[n_objects:]) ** -xv - 1) / xv
+            l = np.round(key) + np.round(value)
             return np.clip(l, 1, self.max_len).astype(np.int64)
         values = np.asarray(self.values, dtype=np.int64)
         if len(values) != n_objects:

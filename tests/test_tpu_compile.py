@@ -120,11 +120,12 @@ def test_flash_attention_compiles(one_chip):
     )
 
 
-def _drive_args(sharding, J, N, n, R=None):
+def _drive_args(sharding, J, N, n, R=None, vdt=jnp.int32):
     """Argument shapes of one chunk of the drive loop: carried state,
-    the chunk's (proxy, object) columns, then the runner's constants."""
+    the chunk's (proxy, object) columns, then the runner's constants
+    (virtual lengths of type ``vdt``)."""
     i32 = jnp.int32
-    state = jax.eval_shape(lambda: fastsim_jax._init_state(J, N, batch=R))
+    state = jax.eval_shape(lambda: fastsim_jax._init_state(J, N, batch=R, vdt=vdt))
     state = jax.tree_util.tree_map(
         lambda s: _spec(sharding, s.shape, s.dtype), state
     )
@@ -136,9 +137,9 @@ def _drive_args(sharding, J, N, n, R=None):
         _spec(sharding, lane + (n,), i32),
         scalar,
         _spec(sharding, (N,), i32),
-        _spec(sharding, (J,), i32),
-        _spec(sharding, (J,), i32),
-        _spec(sharding, (J + 2,), i32),
+        _spec(sharding, (J,), vdt),
+        _spec(sharding, (J,), vdt),
+        _spec(sharding, (J + 2,), vdt),
         scalar,
         scalar,
         scalar,
@@ -151,6 +152,18 @@ def test_single_lane_drive_compiles_at_fig2_size(one_chip):
     fn = fastsim_jax._single_fn(True, N)
     compiled = fn.lower(*_drive_args(one_chip, 9, N, 250_000)).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_drive_loops_compile_with_byte_sized_items(one_chip):
+    # ETC items in bytes at Fig-2 size: int64 virtual lengths
+    N = 1_000_000
+    with fastsim_jax._wide(jnp.int64):
+        fastsim_jax._single_fn(True, N).lower(
+            *_drive_args(one_chip, 9, N, 225_000, vdt=jnp.int64)
+        ).compile()
+        fastsim_jax._batched_fn(True, N).lower(
+            *_drive_args(one_chip, 9, N, 25_000, R=2, vdt=jnp.int64)
+        ).compile()
 
 
 def test_batched_drive_compiles_at_table1_size(one_chip):
