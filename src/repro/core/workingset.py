@@ -274,6 +274,14 @@ def _unpack(vec: np.ndarray, J: int, N: int) -> tuple:
             ints[..., 0], delta[..., 0], ints[..., 1:])
 
 
+def _split(packed, J: int, N: int, b_shape: tuple) -> tuple:
+    """``lam (J, N)``, ``lengths (N,)`` and ``b`` of ``b_shape`` from
+    :func:`_put`'s vector, by static slices (traced inside the solver)."""
+    lam = jnp.reshape(packed[: J * N], (J, N))
+    lengths = packed[J * N : J * N + N]
+    return lam, lengths, jnp.reshape(packed[J * N + N :], b_shape)
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted_solver(
     kind: str,
@@ -292,9 +300,10 @@ def _jitted_solver(
     executable; ``batched=True`` additionally ``vmap``s over a batch of
     allocation vectors so a whole ``b``-grid is one compiled call. The
     executables are named ``jit_ws_solve`` and ``jit_ws_solve_batch``,
-    and return :func:`_pack`'s vector (one per allocation vector).
+    take :func:`_put`'s vector and the static ``J`` and ``N``, and
+    return :func:`_pack`'s vector (one per allocation vector).
     """
-    def ws_solve(lam, lengths, b):
+    def solve(lam, lengths, b):
         return _pack(
             _solve_jax(
                 lam,
@@ -309,11 +318,15 @@ def _jitted_solver(
             )
         )
 
-    def ws_solve_batch(lam, lengths, b_batch):
-        return jax.vmap(ws_solve, in_axes=(None, None, 0))(lam, lengths, b_batch)
+    def ws_solve(packed, J, N):
+        return solve(*_split(packed, J, N, (J,)))
+
+    def ws_solve_batch(packed, J, N):
+        lam, lengths, b_batch = _split(packed, J, N, (-1, J))
+        return jax.vmap(solve, in_axes=(None, None, 0))(lam, lengths, b_batch)
 
     # The function's name is the executable's name in a device trace.
-    return jax.jit(ws_solve_batch if batched else ws_solve)
+    return jax.jit(ws_solve_batch if batched else ws_solve, static_argnums=(1, 2))
 
 
 def _check_inputs(lam, lengths, b, attribution):
@@ -329,6 +342,19 @@ def _check_inputs(lam, lengths, b, attribution):
         )
     if attribution == "full" and np.any(b >= lengths.sum()):
         raise ValueError("b_i >= total catalogue size: cache never evicts")
+
+
+def _put(lam: np.ndarray, lengths: np.ndarray, b: np.ndarray):
+    """The solver's inputs on the device in one host-to-device transfer
+    (counted as ``ws.transfers``): ``lam``, ``lengths`` and ``b`` raveled
+    into one vector of the solver's dtype, float32 (float64 under x64).
+    Numpy's cast rounds to nearest even, as XLA's ``convert`` does, so
+    no conversion runs on the device. :func:`_split` undoes it."""
+    dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+    host = np.concatenate([lam.ravel(), lengths, b.ravel()], dtype=dtype)
+    packed = jax.device_put(host)
+    spans.count("ws.transfers", 1)
+    return packed
 
 
 def _fetch(packed, J: int, N: int) -> tuple:
@@ -377,17 +403,12 @@ def solve_workingset(
         if n_quad is None:
             n_quad = max(8, (J + 1) // 2 + 1)
 
-        dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
         fn = _jitted_solver(
             attribution, n_quad, n_outer, n_bisect, damping, tol, False
         )
-        args = (
-            jnp.asarray(lam, dtype),
-            jnp.asarray(lengths, dtype),
-            jnp.asarray(b, dtype),
-        )
+        packed = _put(lam, lengths, b)
     with spans.span("ws.solve"):
-        out = fn(*args)
+        out = fn(packed, J, N)
         if spans.recording():
             jax.block_until_ready(out)
     with spans.span("ws.collect"):
@@ -478,16 +499,9 @@ def solve_workingset_batch(
     if n_quad is None:
         n_quad = max(8, (J + 1) // 2 + 1)
 
-    dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     fn = _jitted_solver(attribution, n_quad, n_outer, n_bisect, damping, tol, True)
     t, h, L, res, iters, delta, _ = _fetch(
-        fn(
-            jnp.asarray(lam, dtype),
-            jnp.asarray(lengths, dtype),
-            jnp.asarray(b_batch, dtype),
-        ),
-        J,
-        N,
+        fn(_put(lam, lengths, b_batch), J, N), J, N
     )
     t, h, L, res = (np.asarray(x, np.float64) for x in (t, h, L, res))
     converged = _converged(delta, tol)

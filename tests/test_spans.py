@@ -3,11 +3,13 @@
 Under a profiler session, a streamed Monte-Carlo scenario, a two-lane
 XLA ensemble and a working-set solve record every span of their host
 paths, nested under one ``scenario.run`` root per call; the solver
-counts its residual evaluations and the useful ones. With no session,
+counts its residual evaluations and the useful ones, and its one
+transfer each way. With no session,
 nothing is recorded. A small ring counts what it overwrote. The three
 jitted programs carry fixed names into their modules.
 """
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -18,7 +20,8 @@ from repro.scenario import Estimator, Scenario, System, Workload
 
 MC_SPANS = {"mc.trace", "mc.feed", "mc.compile", "mc.drive", "mc.collect"}
 WS_SPANS = {"ws.prepare", "ws.solve", "ws.collect"}
-WS_COUNTERS = {"ws.residual_evals", "ws.residual_evals_useful", "ws.fetches"}
+WS_COUNTERS = {"ws.residual_evals", "ws.residual_evals_useful", "ws.fetches",
+               "ws.transfers"}
 
 
 @pytest.fixture
@@ -109,6 +112,31 @@ def test_one_device_to_host_fetch_per_solve(ring, tmp_path):
     assert spans.events() == events
 
 
+def test_one_host_to_device_transfer_per_solve(ring, tmp_path):
+    sc = scenarios()["ws"]
+    traced(tmp_path, sc, sc)
+    events = spans.events()
+    transfers = [e for e in events if e.name == "ws.transfers"]
+    assert [e.value for e in transfers] == [1, 1]
+    parents = {e.span_id: e.name for e in events if e.value is None}
+    assert all(parents[e.parent_id] == "ws.prepare" for e in transfers)
+    sc.run()  # recording off: nothing more is counted
+    assert spans.events() == events
+
+
+def test_a_solve_at_a_fresh_shape_compiles_only_the_solver(caplog):
+    import logging
+
+    N = 211  # a shape no other test solves at
+    lam = np.full((3, N), 1.0 / N)
+    with caplog.at_level(logging.WARNING), jax.log_compiles(True):
+        workingset.solve_workingset(lam, np.ones(N), np.full(3, 8.0),
+                                    n_outer=5, n_bisect=10)
+    compiled = [r.getMessage().split(" ", 2)[1] for r in caplog.records
+                if r.getMessage().startswith("Compiling ")]
+    assert compiled == ["jit(ws_solve)"]
+
+
 def test_nothing_is_recorded_without_a_profiler_session(ring):
     assert not spans.recording()
     assert spans.span("x") is spans.span("y")  # the shared no-op
@@ -148,8 +176,8 @@ def test_executables_carry_fixed_names(name):
     if name.startswith("jit_ws"):
         batched = name.endswith("batch")
         fn = workingset._jitted_solver("L1", 8, 5, 10, 0.7, 1e-7, batched)
-        b = jnp.full((2, J) if batched else (J,), 4.0)
-        args = (jnp.ones((J, N)), jnp.ones((N,)), b)
+        K = 2 if batched else 1
+        args = (jnp.ones((J * N + N + K * J,)), J, N)
     else:
         lanes = name.endswith("lanes")
         fn = (fastsim_jax._batched_fn if lanes else fastsim_jax._single_fn)(True, N)

@@ -177,9 +177,12 @@ def test_batched_solver_compiles_at_table2_size(one_chip):
     # Table II: the 8 allocation combinations of a J=3, N=1000 system
     J, N, K = 3, 1000, 8
     fn = workingset._jitted_solver("L1", 8, 200, 90, 0.7, 1e-7, True)
-    fn.lower(
-        _spec(one_chip, (J, N), jnp.float32),
-        _spec(one_chip, (N,), jnp.float32),
-        _spec(one_chip, (K, J), jnp.float32),
-    ).compile()
+    fn.lower(_spec(one_chip, (J * N + N + K * J,), jnp.float32), J, N).compile()
+
+
+def test_single_solver_compiles_at_table2_size(one_chip):
+    # one allocation vector of the same system, one packed input vector
+    J, N = 3, 1000
+    fn = workingset._jitted_solver("L1", 8, 200, 90, 0.7, 1e-7, False)
+    fn.lower(_spec(one_chip, (J * N + N + J,), jnp.float32), J, N).compile()
 

@@ -170,6 +170,63 @@ def test_one_fetch_matches_the_outputs_one_by_one(alphas, n, b):
     assert sol.converged is bool(delta <= tol)
 
 
+def _j9_lengths(n):
+    """J=9 at Fig 2's exponents over ``n`` objects of lengths 1..4."""
+    lengths = np.random.default_rng(9).integers(1, 5, size=n).astype(float)
+    return rate_matrix(n, list(FIG2_ALPHAS)), lengths
+
+
+PACKED_CASES = {
+    **{f"table2_single_{i}": ("table2", b, False)
+       for i, b in enumerate(itertools.product((8.0, 64.0), repeat=3))},
+    "table2_batch": ("table2", list(itertools.product((8.0, 64.0), repeat=3)), False),
+    "j9_lengths_single": ("j9", (12.0,) * 3 + (25.0,) * 3 + (90.0,) * 3, False),
+    "j9_lengths_batch": ("j9", [(12.0,) * 3 + (25.0,) * 3 + (90.0,) * 3,
+                               (6.0,) * 3 + (50.0,) * 3 + (40.0,) * 3], False),
+    "table2_x64_single": ("table2", (8.0, 64.0, 8.0), True),
+    "table2_x64_batch": ("table2", [(8.0, 64.0, 8.0), (64.0, 8.0, 64.0)], True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_packed_inputs_match_three_arrays(case):
+    """Inputs moved as one vector cast on the host give, bit for bit,
+    the answers of the solver run on three arrays each moved and cast
+    on the device with ``jnp.asarray``."""
+    system, b, x64 = PACKED_CASES[case]
+    if system == "table2":
+        lam, lengths = rate_matrix(TABLE2_N, list(TABLE2_ALPHAS)), np.ones(TABLE2_N)
+    else:
+        lam, lengths = _j9_lengths(2000)
+    b = np.asarray(b)
+    batched = b.ndim == 2
+    tol, n_quad = 1e-7, max(8, (lam.shape[0] + 1) // 2 + 1)
+    with jax.enable_x64(x64):
+        if batched:
+            sols = solve_workingset_batch(lam, lengths, b, attribution="L1")
+        else:
+            sols = [solve_workingset(lam, lengths, b, attribution="L1")]
+        solve = functools.partial(
+            workingset._solve_jax, kind="L1", n_quad=n_quad, n_outer=200,
+            n_bisect=90, damping=0.7, tol=tol,
+        )
+        if batched:
+            solve = jax.vmap(solve, in_axes=(None, None, 0))
+        dtype = jnp.float64 if x64 else jnp.float32
+        t, h, L, res, iters, delta, _ = jax.jit(solve)(
+            *(jnp.asarray(x, dtype) for x in (lam, lengths, b))
+        )
+        want_converged = np.asarray(delta <= tol)
+    assert t.dtype == dtype
+    lanes = range(len(b)) if batched else [...]
+    for sol, k in zip(sols, lanes):
+        for got, want in ((sol.t, t), (sol.h, h), (sol.L, L), (sol.residual, res)):
+            assert got.dtype == np.float64
+            assert np.array_equal(got, np.asarray(want[k], np.float64))
+        assert sol.iterations == int(iters[k])
+        assert sol.converged is bool(want_converged[k])
+
+
 @pytest.mark.parametrize("step", [-1, 0, 1])
 def test_host_stop_test_matches_the_device_comparison(step):
     """At float32(tol) and at its two float32 neighbours."""
