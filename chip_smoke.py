@@ -17,9 +17,10 @@ fig2        the paper's Fig. 2 scenario (J=9 proxies, N=1e6 objects,
 table1      the Table-I scenario as an 8-lane ensemble in one compiled
             program; every lane must equal the sequential host run of
             its seed, bit for bit.
-workingset  the working-set fixed point (float32) for the 8 Table-II
-            allocations and for a batch at Fig-2 size, on the TPU and on
-            the host's CPU device in this process; every solve must
+workingset  the working-set fixed point (float32), one
+            ``solve_workingset`` call per allocation, for the 8 Table-II
+            allocations and two at Fig-2 size, on the TPU and on the
+            host's CPU device in this process; every solve must
             converge and ``h`` must agree within ``WS_H_ATOL``. Each
             device's float32 ``expm1``/``exp`` error at the solver's
             arguments is printed beside the gap.
@@ -296,7 +297,7 @@ def float32_exp_errors(x, device) -> dict:
 
 
 def workingset_phase(dev) -> None:
-    from repro.core.workingset import solve_workingset_batch
+    from repro.core.workingset import solve_workingset
     from repro.scenario import get_preset
     from repro.scenario.presets import FIG2_B_UNITS
 
@@ -320,9 +321,9 @@ def workingset_phase(dev) -> None:
         for where, device in (("tpu", dev), ("host_cpu", cpu)):
             t0 = time.perf_counter()
             with jax.default_device(device):
-                sols[where] = solve_workingset_batch(lam, lengths, grid)
+                sols[where] = [solve_workingset(lam, lengths, b) for b in grid]
             log(
-                f"workingset {name}: {where} batch={len(grid)} "
+                f"workingset {name}: {where} solves={len(grid)} "
                 f"wall_s={time.perf_counter() - t0:.3f} "
                 f"iterations={[s.iterations for s in sols[where]]} "
                 f"converged={[s.converged for s in sols[where]]}"

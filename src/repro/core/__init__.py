@@ -59,7 +59,6 @@ from .workingset import (  # noqa: F401
     expected_inverse_one_plus,
     hit_probabilities,
     solve_workingset,
-    solve_workingset_batch,
     solve_workingset_unshared,
     virtual_footprint,
 )

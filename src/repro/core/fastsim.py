@@ -989,6 +989,55 @@ def simulate_chunks(
         )
 
 
+def simulate_replicas(
+    params: SimParams,
+    draw,
+    replications: int,
+    n_objects: int,
+    n_requests: int,
+    *,
+    lengths: np.ndarray,
+    warmup: int,
+    ripple_from: Optional[int],
+    engine: str,
+    streaming: bool,
+) -> Tuple[List[SimResult], bool]:
+    """Drive ``replications`` R >= 1 independent replicas of one cache.
+
+    ``draw(r)`` gives replica ``r``'s request stream: an iterable of
+    :class:`~repro.core.irm.IRMTrace` chunks of ``n_requests`` in all.
+    R > 1 replicas of the flat shared LRU on ``engine="xla"``, inside
+    the XLA driver's envelope, run as lanes of one compiled program
+    (:func:`repro.core.fastsim_jax.simulate_ensemble`); every other run
+    drives the replicas one after another through
+    :func:`simulate_chunks`, drawing each stream only when its turn
+    comes. Occupancy is sparse exactly when ``streaming``. Returns the R
+    results and whether they shared one program.
+    """
+    R = int(replications)
+    kw = dict(lengths=lengths, warmup=warmup, ripple_from=ripple_from)
+    if (
+        R > 1
+        and engine == "xla"
+        and params.variant == "lru"
+        and not params.batch_interval
+        and _xla_applicable(n_requests, n_objects, np.asarray(lengths), params)
+    ):
+        from . import fastsim_jax
+
+        streams = [draw(r) for r in range(R)]
+        return fastsim_jax.simulate_ensemble(
+            params, streams, n_objects, n_requests, sparse=streaming, **kw
+        ), True
+    return [
+        simulate_chunks(
+            params, draw(r), n_objects, n_requests,
+            engine=engine, sparse=streaming, **kw,
+        )
+        for r in range(R)
+    ], False
+
+
 def make_chunk_driver(
     params: SimParams,
     n_objects: int,

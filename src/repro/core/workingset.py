@@ -263,23 +263,19 @@ def _pack(outputs) -> jnp.ndarray:
 
 
 def _unpack(vec: np.ndarray, J: int, N: int) -> tuple:
-    """``t, h, L, res, iters, delta, work`` from :func:`_pack`'s vector,
-    over any leading (batch) axes."""
-    lead = vec.shape[:-1]
+    """``t, h, L, res, iters, delta, work`` from :func:`_pack`'s vector."""
     t, h, L, res, delta, ints = np.split(
-        vec, np.cumsum([J, J * N, J * N, J, 1]), axis=-1
+        vec, np.cumsum([J, J * N, J * N, J, 1])
     )
     ints = ints.view(f"i{vec.itemsize}")
-    return (t, h.reshape(lead + (J, N)), L.reshape(lead + (J, N)), res,
-            ints[..., 0], delta[..., 0], ints[..., 1:])
+    return t, h.reshape(J, N), L.reshape(J, N), res, ints[0], delta[0], ints[1:]
 
 
-def _split(packed, J: int, N: int, b_shape: tuple) -> tuple:
-    """``lam (J, N)``, ``lengths (N,)`` and ``b`` of ``b_shape`` from
-    :func:`_put`'s vector, by static slices (traced inside the solver)."""
+def _split(packed, J: int, N: int) -> tuple:
+    """``lam (J, N)``, ``lengths (N,)`` and ``b (J,)`` from :func:`_put`'s
+    vector, by static slices (traced inside the solver)."""
     lam = jnp.reshape(packed[: J * N], (J, N))
-    lengths = packed[J * N : J * N + N]
-    return lam, lengths, jnp.reshape(packed[J * N + N :], b_shape)
+    return lam, packed[J * N : J * N + N], packed[J * N + N :]
 
 
 @functools.lru_cache(maxsize=None)
@@ -290,25 +286,20 @@ def _jitted_solver(
     n_bisect: int,
     damping: float,
     tol: float,
-    batched: bool,
 ):
     """One jit-compiled solver per hyperparameter set (cached).
 
     Previously every ``solve_workingset`` call wrapped a fresh
     ``functools.partial`` in ``jax.jit``, so the Table-II sweep paid 8
     compilations for 8 identical-shape solves. The cache reuses the
-    executable; ``batched=True`` additionally ``vmap``s over a batch of
-    allocation vectors so a whole ``b``-grid is one compiled call. The
-    executables are named ``jit_ws_solve`` and ``jit_ws_solve_batch``,
-    take :func:`_put`'s vector and the static ``J`` and ``N``, and
-    return :func:`_pack`'s vector (one per allocation vector).
+    executable, named ``jit_ws_solve``: it takes :func:`_put`'s vector
+    and the static ``J`` and ``N``, and returns :func:`_pack`'s vector.
     """
-    def solve(lam, lengths, b):
+
+    def ws_solve(packed, J, N):
         return _pack(
             _solve_jax(
-                lam,
-                lengths,
-                b,
+                *_split(packed, J, N),
                 kind=kind,
                 n_quad=n_quad,
                 n_outer=n_outer,
@@ -318,20 +309,13 @@ def _jitted_solver(
             )
         )
 
-    def ws_solve(packed, J, N):
-        return solve(*_split(packed, J, N, (J,)))
-
-    def ws_solve_batch(packed, J, N):
-        lam, lengths, b_batch = _split(packed, J, N, (-1, J))
-        return jax.vmap(solve, in_axes=(None, None, 0))(lam, lengths, b_batch)
-
     # The function's name is the executable's name in a device trace.
-    return jax.jit(ws_solve_batch if batched else ws_solve, static_argnums=(1, 2))
+    return jax.jit(ws_solve, static_argnums=(1, 2))
 
 
 def _check_inputs(lam, lengths, b, attribution):
     J, N = lam.shape
-    if lengths.shape != (N,) or b.shape[-1] != J:
+    if lengths.shape != (N,) or b.shape != (J,):
         raise ValueError("shape mismatch between lam, lengths, b")
     if attribution not in ATTRIBUTIONS:
         raise ValueError(f"unknown attribution {attribution!r}")
@@ -351,7 +335,7 @@ def _put(lam: np.ndarray, lengths: np.ndarray, b: np.ndarray):
     Numpy's cast rounds to nearest even, as XLA's ``convert`` does, so
     no conversion runs on the device. :func:`_split` undoes it."""
     dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
-    host = np.concatenate([lam.ravel(), lengths, b.ravel()], dtype=dtype)
+    host = np.concatenate([lam.ravel(), lengths, b], dtype=dtype)
     packed = jax.device_put(host)
     spans.count("ws.transfers", 1)
     return packed
@@ -396,16 +380,12 @@ def solve_workingset(
         lengths = np.asarray(lengths, dtype=np.float64)
         b = np.asarray(b, dtype=np.float64)
         J, N = lam.shape
-        if b.shape != (J,):
-            raise ValueError("shape mismatch between lam, lengths, b")
         _check_inputs(lam, lengths, b, attribution)
 
         if n_quad is None:
             n_quad = max(8, (J + 1) // 2 + 1)
 
-        fn = _jitted_solver(
-            attribution, n_quad, n_outer, n_bisect, damping, tol, False
-        )
+        fn = _jitted_solver(attribution, n_quad, n_outer, n_bisect, damping, tol)
         packed = _put(lam, lengths, b)
     with spans.span("ws.solve"):
         out = fn(packed, J, N)
@@ -462,58 +442,3 @@ def virtual_footprint(
         attribution_matrix(jnp.asarray(h), jnp.asarray(lengths), attribution, n_quad)
     )
     return (h * L).sum(axis=1)
-
-
-def solve_workingset_batch(
-    lam,
-    lengths,
-    b_batch,
-    attribution: str = "L1",
-    *,
-    n_quad: int | None = None,
-    n_outer: int = 200,
-    n_bisect: int = 90,
-    damping: float = 0.7,
-    tol: float = 1e-7,
-) -> list:
-    """Solve eq. (8) for a whole batch of allocation vectors at once.
-
-    ``b_batch``: (K, J) — e.g. the 8 Table-II ``b``-combinations. One
-    ``jax.vmap``-ed jit call replaces K sequential solves (and K
-    recompilations under the old per-call jit), so the Table-II sweep
-    compiles once and solves the grid in a single XLA execution. The
-    batched while-loop iterates until the *slowest* combo converges;
-    per-combo ``converged`` is still reported from its final delta.
-
-    Returns a list of K :class:`WorkingSetSolution`.
-    """
-    lam = np.asarray(lam, dtype=np.float64)
-    lengths = np.asarray(lengths, dtype=np.float64)
-    b_batch = np.atleast_2d(np.asarray(b_batch, dtype=np.float64))
-    J, N = lam.shape
-    if b_batch.shape[1] != J:
-        raise ValueError("b_batch must be (K, J)")
-    for b in b_batch:
-        _check_inputs(lam, lengths, b, attribution)
-
-    if n_quad is None:
-        n_quad = max(8, (J + 1) // 2 + 1)
-
-    fn = _jitted_solver(attribution, n_quad, n_outer, n_bisect, damping, tol, True)
-    t, h, L, res, iters, delta, _ = _fetch(
-        fn(_put(lam, lengths, b_batch), J, N), J, N
-    )
-    t, h, L, res = (np.asarray(x, np.float64) for x in (t, h, L, res))
-    converged = _converged(delta, tol)
-    out = []
-    for k in range(b_batch.shape[0]):
-        sol = WorkingSetSolution(
-            t=t[k],
-            h=h[k],
-            L=L[k],
-            residual=res[k],
-            iterations=int(iters[k]),
-            converged=bool(converged[k]),
-        )
-        out.append(sol.with_rates(lam))
-    return out

@@ -2,18 +2,19 @@
 
 Dispatches on the system and estimator:
 
-* ``monte_carlo`` — samples the workload's trace and drives it through
-  :func:`repro.core.fastsim.simulate_trace` (C / inlined-Python / XLA
-  backends) or, with ``System(backend="reference")``, through the
+* ``monte_carlo`` — samples the workload's trace for each of R >= 1
+  replicas and drives it through
+  :func:`repro.core.fastsim.simulate_replicas` (C / inlined-Python /
+  XLA backends) or, with ``System(backend="reference")``, through the
   hookable executable-spec caches of :mod:`repro.core.shared_lru` /
   :mod:`repro.core.slru` (event-equivalent, orders of magnitude slower —
   small runs and debugging). Large runs stream instead: past the
   ``STREAMING_*`` thresholds (or with ``Estimator(streaming=True)``)
-  the trace is fed chunk by chunk through
-  :func:`repro.core.fastsim.simulate_chunks` and occupancy comes back
-  sparse — same results, O(chunk + touched-set) memory. The trace and
+  the trace is fed chunk by chunk and occupancy comes back sparse —
+  same results, O(chunk + touched-set) memory. The trace and
   object-length draws use independent seed substreams derived from
-  ``Scenario.seed`` (:func:`derive_seeds`).
+  ``Scenario.seed`` (:func:`derive_seeds`). Every Monte-Carlo Report,
+  the cluster's included, comes from :func:`_ensemble_report`.
 * ``working_set`` — solves the paper's eq. (8) fixed point
   (:func:`repro.core.workingset.solve_workingset`) on the workload's
   (time-average) rate matrix. No trace is sampled.
@@ -56,8 +57,7 @@ from repro.core.fastsim import (
     SimResult,
     SparseOccupancy,
     default_warmup,
-    simulate_chunks,
-    simulate_trace,
+    simulate_replicas,
 )
 from repro.core.irm import IRMTrace, PopularityEstimator, sample_trace
 from repro.core.metrics import OccupancyRecorder
@@ -251,8 +251,9 @@ def _run_working_set(sc: Scenario) -> Report:
 # ---------------------------------------------------------------------------
 # Monte-Carlo estimator
 # ---------------------------------------------------------------------------
-def _run_monte_carlo(sc: Scenario) -> Report:
-    system = sc.system
+def _mc_setup(sc: Scenario) -> Tuple[int, int, bool, np.ndarray, int]:
+    """What every Monte-Carlo run fixes before it draws a request:
+    ``(n, trace_seed, streaming, lengths, warmup)``."""
     n = sc.n_requests
     if sc.workload.kind == "trace" and n < 1:
         n = len(sc.workload.trace_proxies)
@@ -262,49 +263,51 @@ def _run_monte_carlo(sc: Scenario) -> Report:
     warmup = (
         sc.warmup
         if sc.warmup is not None
-        else default_warmup(n, system.allocations)
+        else default_warmup(n, sc.system.allocations)
     )
-    warmup = min(warmup, n)
-    if sc.estimator.replications > 1:
-        return _run_monte_carlo_ensemble(
-            sc, n, warmup, lengths, trace_seed, streaming
-        )
+    return n, trace_seed, streaming, lengths, min(warmup, n)
+
+
+def _run_monte_carlo(sc: Scenario) -> Report:
+    """R >= 1 replicas of the system (``Estimator(replications=R)``).
+
+    Replica ``r`` draws its trace from ``ensemble_seeds(...)[r]``
+    (replica 0 is bit-identical to a single run), one-shot or, past the
+    streaming thresholds, chunk by chunk (bit-identical results, O(chunk
+    + touched-set) memory). ``backend="reference"`` drives each replica
+    through the executable spec (:func:`_run_reference`); every other
+    backend goes through :func:`repro.core.fastsim.simulate_replicas`,
+    which runs R > 1 replicas on ``backend="xla"`` as lanes of one
+    compiled program and every other run replica by replica.
+    """
+    system = sc.system
+    n, trace_seed, streaming, lengths, warmup = _mc_setup(sc)
+    seeds = ensemble_seeds(trace_seed, sc.estimator.replications)
     if system.backend == "reference":
-        trace = _sample(sc, n, trace_seed)
-        res = _run_reference(sc, trace, lengths, warmup)
-        backend = "reference"
-    elif streaming:
-        # Chunk-fed drive loop + sparse touched-set occupancy: the trace
-        # is never materialized in full, and the result is bit-identical
-        # to the one-shot dense path (tests/test_streaming.py).
-        res = simulate_chunks(
+        results = [
+            _run_reference(sc, _sample(sc, n, s), lengths, warmup) for s in seeds
+        ]
+        batched = False
+    else:
+
+        def draw(r: int):
+            if streaming:
+                return _chunks(sc, n, seeds[r])
+            return (_sample(sc, n, seeds[r]),)
+
+        results, batched = simulate_replicas(
             system.to_sim_params(),
-            _chunks(sc, n, trace_seed),
+            draw,
+            len(seeds),
             sc.workload.n_objects,
             n,
             lengths=lengths,
             warmup=warmup,
             ripple_from=sc.ripple_from,
             engine=system.backend,
-            sparse=True,
+            streaming=streaming,
         )
-        backend = res.engine
-    else:
-        trace = _sample(sc, n, trace_seed)
-        res = simulate_trace(
-            system.to_sim_params(),
-            trace,
-            sc.workload.n_objects,
-            lengths=lengths,
-            warmup=warmup,
-            ripple_from=sc.ripple_from,
-            engine=system.backend,
-        )
-        # SimResult records the backend that actually ran (under "auto"
-        # the C path can silently fall back to the Python loop).
-        backend = res.engine
-    with spans.span("mc.collect"):
-        return _monte_carlo_report(sc, res, backend, streaming)
+    return _ensemble_report(sc, results, streaming, batched)
 
 
 def _sample(sc: Scenario, n: int, seed: int) -> IRMTrace:
@@ -317,67 +320,6 @@ def _chunks(sc: Scenario, n: int, seed: int):
     return spans.each(
         "mc.trace",
         sc.workload.iter_chunks(n, seed, chunk_size=sc.estimator.chunk_size),
-    )
-
-
-def _monte_carlo_report(
-    sc: Scenario, res: SimResult, backend: str, streaming: bool
-) -> Report:
-    system = sc.system
-    lam = _rates_for(sc)
-    per_proxy, overall = _hit_rates(res.occupancy, lam)
-    ripple = None
-    if system.variant in ("lru", "slru"):
-        ripple = {
-            "evictions_per_set": {
-                str(k): int(c)
-                for k, c in enumerate(res.evictions_per_set)
-                if c
-            },
-            "n_sets_recorded": int(res.n_sets_recorded),
-            "n_primary": int(res.n_primary),
-            "n_ripple": int(res.n_ripple),
-            "n_batch_evictions": int(res.n_batch_evictions),
-            "frac_multi_eviction": float(res.frac_multi_eviction),
-            "mean_evictions": float(res.mean_evictions),
-        }
-        _count_evictions(ripple)
-    return Report(
-        scenario=sc.to_dict(),
-        estimator="monte_carlo",
-        backend=backend,
-        hit_prob=res.occupancy,
-        hit_rate=per_proxy,
-        overall_hit_rate=overall,
-        n_requests=res.n_requests,
-        warmup=res.warmup,
-        elapsed_s=res.elapsed_s,
-        throughput_rps=res.requests_per_sec,
-        realized_hit_rate=res.hit_rate_by_proxy,
-        ripple=ripple,
-        final_vlen=np.asarray(res.final_vlen, dtype=np.float64),
-        extras={
-            "n_hit_list": int(res.n_hit_list),
-            "n_hit_cache": int(res.n_hit_cache),
-            "n_miss": int(res.n_miss),
-            "streaming": bool(streaming),
-            **(
-                {"chunk_size": int(sc.estimator.chunk_size)}
-                if streaming
-                else {}
-            ),
-        },
-    )
-
-
-def _count_evictions(ripple: dict) -> None:
-    """A Report's eviction work, from the drive loop's histogram state:
-    counters ``mc.evictions`` (objects evicted by recorded sets) and
-    ``mc.sets`` (recorded sets that evicted at least one)."""
-    spans.count("mc.evictions", ripple["n_primary"] + ripple["n_ripple"])
-    spans.count(
-        "mc.sets",
-        ripple["n_sets_recorded"] - ripple["evictions_per_set"].get("0", 0),
     )
 
 
@@ -396,10 +338,10 @@ def _run_cluster(sc: Scenario) -> Report:
     Samples the scenario trace once, routes it through
     :func:`repro.core.cluster.simulate_cluster` (per-node fastsim
     engines behind the ring + failover client), and reports the
-    aggregate exactly like :func:`_run_monte_carlo` — with ``nodes=1``
-    and an empty :class:`~repro.core.cluster.FaultSpec` the estimates
-    are bit-identical to the single-node path. The cluster telemetry
-    (phases, windows, remaps, retries, recovery) lands in
+    aggregate through the same builder as :func:`_run_monte_carlo` —
+    with ``nodes=1`` and an empty :class:`~repro.core.cluster.FaultSpec`
+    the Report is bit-identical to the single-node one apart from the
+    cluster telemetry (phases, windows, remaps, retries, recovery) in
     ``Report.extras["cluster"]``. ``System(executor="parallel",
     workers=W)`` fans the per-node feeding pass out over a
     :class:`~repro.core.cluster.ClusterExecutor` process pool with
@@ -415,22 +357,10 @@ def _run_cluster(sc: Scenario) -> Report:
         raise ValueError(
             "cluster systems do not support ensemble replications yet"
         )
-    n = sc.n_requests
-    if sc.workload.kind == "trace" and n < 1:
-        n = len(sc.workload.trace_proxies)
-    trace_seed, length_seed = derive_seeds(sc.seed)
-    streaming = use_streaming(sc, n)
-    lengths = sc.workload.object_lengths(length_seed)
-    warmup = (
-        sc.warmup
-        if sc.warmup is not None
-        else default_warmup(n, system.allocations)
-    )
-    warmup = min(warmup, n)
-    trace = sc.workload.sample(n, trace_seed)
+    n, trace_seed, streaming, lengths, warmup = _mc_setup(sc)
     res, cluster = simulate_cluster(
         system.to_sim_params(),
-        trace,
+        _sample(sc, n, trace_seed),
         sc.workload.n_objects,
         nodes=system.nodes,
         faults=system.faults,
@@ -446,130 +376,7 @@ def _run_cluster(sc: Scenario) -> Report:
         # single-node chunked path; results are split-invariant
         chunk_size=est.chunk_size if streaming else None,
     )
-    lam = _rates_for(sc)
-    per_proxy, overall = _hit_rates(res.occupancy, lam)
-    ripple = {
-        "evictions_per_set": {
-            str(k): int(c) for k, c in enumerate(res.evictions_per_set) if c
-        },
-        "n_sets_recorded": int(res.n_sets_recorded),
-        "n_primary": int(res.n_primary),
-        "n_ripple": int(res.n_ripple),
-        "n_batch_evictions": int(res.n_batch_evictions),
-        "frac_multi_eviction": float(res.frac_multi_eviction),
-        "mean_evictions": float(res.mean_evictions),
-    }
-    return Report(
-        scenario=sc.to_dict(),
-        estimator="monte_carlo",
-        backend=res.engine,
-        hit_prob=res.occupancy,
-        hit_rate=per_proxy,
-        overall_hit_rate=overall,
-        n_requests=res.n_requests,
-        warmup=res.warmup,
-        elapsed_s=res.elapsed_s,
-        throughput_rps=res.requests_per_sec,
-        realized_hit_rate=res.hit_rate_by_proxy,
-        ripple=ripple,
-        final_vlen=np.asarray(res.final_vlen, dtype=np.float64),
-        extras={
-            "n_hit_list": int(res.n_hit_list),
-            "n_hit_cache": int(res.n_hit_cache),
-            "n_miss": int(res.n_miss),
-            "streaming": bool(streaming),
-            "cluster": cluster,
-        },
-    )
-
-
-def _run_monte_carlo_ensemble(
-    sc: Scenario,
-    n: int,
-    warmup: int,
-    lengths: np.ndarray,
-    trace_seed: int,
-    streaming: bool,
-) -> Report:
-    """R-replica Monte-Carlo run (``Estimator(replications=R)``).
-
-    Replica trace seeds come from :func:`ensemble_seeds` (replica 0 is
-    bit-identical to a single run). On ``backend="xla"`` (flat LRU, no
-    delayed batching) all replicas execute batched inside one compiled
-    XLA program via :func:`repro.core.fastsim_jax.simulate_ensemble`;
-    every other backend runs the replicas sequentially with identical
-    per-replica results. The Report carries cross-replica means in the
-    main fields and the per-replica estimates in ``Report.ensemble``.
-    """
-    from repro.core.fastsim import _xla_applicable
-
-    system, est = sc.system, sc.estimator
-    R = est.replications
-    seeds = ensemble_seeds(trace_seed, R)
-    params = system.to_sim_params()
-    ripple_from = sc.ripple_from
-    batched = False
-    results = None
-    if (
-        system.backend == "xla"
-        and system.variant == "lru"
-        and system.batch_interval == 0
-        and _xla_applicable(
-            n, sc.workload.n_objects, np.asarray(lengths), params
-        )
-    ):
-        from repro.core import fastsim_jax
-
-        if streaming:
-            traces = [_chunks(sc, n, s) for s in seeds]
-        else:
-            traces = [_sample(sc, n, s) for s in seeds]
-        results = fastsim_jax.simulate_ensemble(
-            params,
-            traces,
-            sc.workload.n_objects,
-            n,
-            lengths=lengths,
-            warmup=warmup,
-            ripple_from=ripple_from,
-            sparse=streaming,
-        )
-        batched = True
-    if results is None:
-        results = []
-        for s in seeds:
-            if system.backend == "reference":
-                res = _run_reference(sc, _sample(sc, n, s), lengths, warmup)
-                res.engine = "reference"
-                results.append(res)
-            elif streaming:
-                results.append(
-                    simulate_chunks(
-                        params,
-                        _chunks(sc, n, s),
-                        sc.workload.n_objects,
-                        n,
-                        lengths=lengths,
-                        warmup=warmup,
-                        ripple_from=ripple_from,
-                        engine=system.backend,
-                        sparse=True,
-                    )
-                )
-            else:
-                results.append(
-                    simulate_trace(
-                        params,
-                        _sample(sc, n, s),
-                        sc.workload.n_objects,
-                        lengths=lengths,
-                        warmup=warmup,
-                        ripple_from=ripple_from,
-                        engine=system.backend,
-                    )
-                )
-    with spans.span("mc.collect"):
-        return _ensemble_report(sc, results, streaming, batched)
+    return _ensemble_report(sc, [res], streaming, False, {"cluster": cluster})
 
 
 # Cap on the stacked (R, J, N) per-replica hit-probability payload kept
@@ -579,116 +386,143 @@ ENSEMBLE_HIT_PROB_CELLS = 32_000_000
 
 
 def _ensemble_report(
-    sc: Scenario, results, streaming: bool, batched: bool
+    sc: Scenario, results, streaming: bool, batched: bool, extras=None
 ) -> Report:
-    """Aggregate per-replica SimResults into one ensemble Report."""
-    R = len(results)
-    lam = _rates_for(sc)
-    per = [_hit_rates(r.occupancy, lam) for r in results]
-    hit_rate_stack = np.stack([p for p, _ in per])  # (R, J)
-    overall_stack = np.asarray([o for _, o in per], dtype=np.float64)
-    realized_stack = np.stack([r.hit_rate_by_proxy for r in results])
+    """Every Monte-Carlo Report, built under ``mc.collect`` from the
+    SimResults of R >= 1 replicas, or of one cluster run whose telemetry
+    comes in ``extras``.
 
-    sparse_any = any(
-        isinstance(r.occupancy, SparseOccupancy) for r in results
-    )
-    N = sc.workload.n_objects
-    J = hit_rate_stack.shape[1]
-    if sparse_any:
-        # union of touched sets; untouched columns are exactly zero
-        idx = np.unique(
-            np.concatenate([r.occupancy.indices for r in results])
+    One result's estimates are the Report's own. R > 1 replicas give
+    the cross-replica means in the main fields and the per-replica
+    estimates in ``Report.ensemble``. Batched replicas share one wall
+    clock; sequential replicas add up.
+    """
+    with spans.span("mc.collect"):
+        lam = _rates_for(sc)
+        per = [_hit_rates(r.occupancy, lam) for r in results]
+        ensemble = None
+        if len(results) == 1:
+            res = results[0]
+            hit_prob, (hit_rate, overall) = res.occupancy, per[0]
+            realized = res.hit_rate_by_proxy
+            final_vlen = np.asarray(res.final_vlen, dtype=np.float64)
+        else:
+            hit_prob, prob_stack = _mean_occupancy(sc, results)
+            ensemble = {
+                "replications": len(results),
+                "batched": bool(batched),
+                "hit_rate": np.stack([p for p, _ in per]),  # (R, J)
+                "overall_hit_rate": np.asarray(
+                    [o for _, o in per], dtype=np.float64
+                ),
+                "realized_hit_rate": np.stack(
+                    [r.hit_rate_by_proxy for r in results]
+                ),
+            }
+            if prob_stack is not None:
+                ensemble["hit_prob"] = prob_stack
+            hit_rate = ensemble["hit_rate"].mean(axis=0)
+            overall = float(ensemble["overall_hit_rate"].mean())
+            realized = ensemble["realized_hit_rate"].mean(axis=0)
+            final_vlen = np.stack(
+                [np.asarray(r.final_vlen, dtype=np.float64) for r in results]
+            ).mean(axis=0)
+        elapsed = (
+            results[0].elapsed_s
+            if batched
+            else sum(r.elapsed_s for r in results)
         )
-        acc = np.zeros((J, idx.size), dtype=np.float64)
-        for r in results:
-            occ = r.occupancy
-            pos = np.searchsorted(idx, occ.indices)
-            acc[:, pos] += occ.values
-        hit_prob = SparseOccupancy(N, idx, acc / R)
-        # small catalogues still get per-object error bars: densify the
-        # per-replica stack when it fits the cap (streaming may have
-        # been chosen for the trace length, not the state size)
-        prob_stack = (
-            np.stack([r.occupancy.densify() for r in results])
-            if R * J * N <= ENSEMBLE_HIT_PROB_CELLS
-            else None
+        n_total = sum(r.n_requests for r in results)
+        return Report(
+            scenario=sc.to_dict(),
+            estimator="monte_carlo",
+            backend=results[0].engine,
+            hit_prob=hit_prob,
+            hit_rate=hit_rate,
+            overall_hit_rate=overall,
+            n_requests=n_total,
+            warmup=results[0].warmup,
+            elapsed_s=elapsed,
+            throughput_rps=n_total / elapsed if elapsed > 0 else float("inf"),
+            realized_hit_rate=realized,
+            ripple=_ripple(sc, results),
+            final_vlen=final_vlen,
+            ensemble=ensemble,
+            extras={
+                "n_hit_list": int(sum(r.n_hit_list for r in results)),
+                "n_hit_cache": int(sum(r.n_hit_cache for r in results)),
+                "n_miss": int(sum(r.n_miss for r in results)),
+                "streaming": bool(streaming),
+                **(
+                    {"chunk_size": int(sc.estimator.chunk_size)}
+                    if streaming
+                    else {}
+                ),
+                **(extras or {}),
+            },
         )
-    else:
+
+
+def _mean_occupancy(sc: Scenario, results):
+    """The replicas' mean occupancy, and their (R, J, N) stack where it
+    fits :data:`ENSEMBLE_HIT_PROB_CELLS` (else None)."""
+    R, N = len(results), sc.workload.n_objects
+    if not any(isinstance(r.occupancy, SparseOccupancy) for r in results):
         stack = np.stack([r.occupancy for r in results])
-        hit_prob = stack.mean(axis=0)
-        prob_stack = (
+        return stack.mean(axis=0), (
             stack if stack.size <= ENSEMBLE_HIT_PROB_CELLS else None
         )
-
-    ripple = None
-    if sc.system.variant in ("lru", "slru"):
-        hist_len = max(len(r.evictions_per_set) for r in results)
-        hist = np.zeros(hist_len, dtype=np.int64)
-        for r in results:
-            hist[: len(r.evictions_per_set)] += r.evictions_per_set
-        n_sets = sum(r.n_sets_recorded for r in results)
-        ks = np.arange(hist_len)
-        ripple = {
-            "evictions_per_set": {
-                str(k): int(c) for k, c in enumerate(hist) if c
-            },
-            "n_sets_recorded": int(n_sets),
-            "n_primary": int(sum(r.n_primary for r in results)),
-            "n_ripple": int(sum(r.n_ripple for r in results)),
-            "n_batch_evictions": int(
-                sum(r.n_batch_evictions for r in results)
-            ),
-            "frac_multi_eviction": float(
-                hist[2:].sum() / n_sets if n_sets else 0.0
-            ),
-            "mean_evictions": float(
-                (ks * hist).sum() / n_sets if n_sets else 0.0
-            ),
-        }
-        _count_evictions(ripple)
-
-    # Batched replicas share one wall clock; sequential replicas add up.
-    elapsed = (
-        results[0].elapsed_s if batched else sum(r.elapsed_s for r in results)
+    # union of touched sets; untouched columns are exactly zero
+    idx = np.unique(np.concatenate([r.occupancy.indices for r in results]))
+    J = sc.system.n_proxies
+    acc = np.zeros((J, idx.size), dtype=np.float64)
+    for r in results:
+        acc[:, np.searchsorted(idx, r.occupancy.indices)] += r.occupancy.values
+    # small catalogues still get per-object error bars: densify the
+    # per-replica stack when it fits the cap (streaming may have been
+    # chosen for the trace length, not the state size)
+    fits = R * J * N <= ENSEMBLE_HIT_PROB_CELLS
+    return SparseOccupancy(N, idx, acc / R), (
+        np.stack([r.occupancy.densify() for r in results]) if fits else None
     )
-    n_total = sum(r.n_requests for r in results)
-    ensemble = {
-        "replications": R,
-        "batched": bool(batched),
-        "hit_rate": hit_rate_stack,
-        "overall_hit_rate": overall_stack,
-        "realized_hit_rate": realized_stack,
+
+
+def _ripple(sc: Scenario, results) -> Optional[dict]:
+    """The results' evictions-per-set statistics, summed and counted
+    (:func:`_count_evictions`); None for the variants without
+    sharing."""
+    if sc.system.variant not in ("lru", "slru"):
+        return None
+    hist = np.zeros(
+        max(len(r.evictions_per_set) for r in results), dtype=np.int64
+    )
+    for r in results:
+        hist[: len(r.evictions_per_set)] += r.evictions_per_set
+    n_sets = int(sum(r.n_sets_recorded for r in results))
+    ks = np.arange(hist.size)
+    ripple = {
+        "evictions_per_set": {str(k): int(c) for k, c in enumerate(hist) if c},
+        "n_sets_recorded": n_sets,
+        "n_primary": int(sum(r.n_primary for r in results)),
+        "n_ripple": int(sum(r.n_ripple for r in results)),
+        "n_batch_evictions": int(sum(r.n_batch_evictions for r in results)),
+        "frac_multi_eviction": float(
+            hist[2:].sum() / n_sets if n_sets else 0.0
+        ),
+        "mean_evictions": float((ks * hist).sum() / n_sets if n_sets else 0.0),
     }
-    if prob_stack is not None:
-        ensemble["hit_prob"] = prob_stack
-    return Report(
-        scenario=sc.to_dict(),
-        estimator="monte_carlo",
-        backend=results[0].engine,
-        hit_prob=hit_prob,
-        hit_rate=hit_rate_stack.mean(axis=0),
-        overall_hit_rate=float(overall_stack.mean()),
-        n_requests=n_total,
-        warmup=results[0].warmup,
-        elapsed_s=elapsed,
-        throughput_rps=n_total / elapsed if elapsed > 0 else float("inf"),
-        realized_hit_rate=realized_stack.mean(axis=0),
-        ripple=ripple,
-        final_vlen=np.stack(
-            [np.asarray(r.final_vlen, dtype=np.float64) for r in results]
-        ).mean(axis=0),
-        ensemble=ensemble,
-        extras={
-            "n_hit_list": int(sum(r.n_hit_list for r in results)),
-            "n_hit_cache": int(sum(r.n_hit_cache for r in results)),
-            "n_miss": int(sum(r.n_miss for r in results)),
-            "streaming": bool(streaming),
-            **(
-                {"chunk_size": int(sc.estimator.chunk_size)}
-                if streaming
-                else {}
-            ),
-        },
+    _count_evictions(ripple)
+    return ripple
+
+
+def _count_evictions(ripple: dict) -> None:
+    """A Report's eviction work, from the drive loop's histogram state:
+    counters ``mc.evictions`` (objects evicted by recorded sets) and
+    ``mc.sets`` (recorded sets that evicted at least one)."""
+    spans.count("mc.evictions", ripple["n_primary"] + ripple["n_ripple"])
+    spans.count(
+        "mc.sets",
+        ripple["n_sets_recorded"] - ripple["evictions_per_set"].get("0", 0),
     )
 
 
@@ -791,6 +625,7 @@ def _run_reference(
         n_batch_evictions=n_batch,
         final_vlen=np.asarray([cache.vlen(i) for i in range(J)]),
         elapsed_s=elapsed,
+        engine="reference",
     )
 
 

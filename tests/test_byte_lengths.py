@@ -12,9 +12,10 @@ single-lane driver on its trace. ``_xla_applicable`` admits these sizes
 and still refuses what no integer type it uses can hold. The ``item``
 length kind draws key plus value bytes by the two laws' quantile
 functions. The front door counts each Report's eviction work under a
-profiler session.
+profiler session, on every Monte-Carlo path, the cluster's included.
 """
 
+import dataclasses
 import json
 import math
 
@@ -187,10 +188,13 @@ def test_item_length_spec_json_round_trip_and_checks():
             LengthSpec(kind="item", **bad)
 
 
-@pytest.mark.parametrize("replications", [1, 2], ids=["one_lane", "lanes"])
-def test_eviction_counters_per_report(replications, monkeypatch, tmp_path):
+@pytest.mark.parametrize("path", ["one_lane", "lanes", "cluster"])
+def test_eviction_counters_per_report(path, monkeypatch, tmp_path):
     monkeypatch.setattr(spans, "_RING", spans.Ring(spans.CAPACITY))
+    replications = 2 if path == "lanes" else 1
     sc = scenario("xla", streaming=True, ghost=True, slack=0.0, replications=replications)
+    if path == "cluster":  # three nodes on the host engine behind the ring
+        sc = dataclasses.replace(sc, system=dataclasses.replace(sc.system, backend="auto", nodes=3))
     with jax.profiler.trace(str(tmp_path)):
         rep = sc.run()
     counts = {e.name: e for e in spans.events() if e.value is not None}

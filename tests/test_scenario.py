@@ -7,7 +7,8 @@ Covers the acceptance contract of the unified API:
 * estimator-vs-simulator agreement on the Table-I preset;
 * the reference backend, the pooled variant, the non-stationary
   shot-noise workload, trace replay with empirical rates, object-size
-  distributions, and the chunked/streaming trace sampler.
+  distributions, and the chunked/streaming trace sampler;
+* one Report shape from every Monte-Carlo path.
 """
 
 import dataclasses
@@ -19,6 +20,7 @@ import pytest
 from repro.core import rate_matrix, sample_trace, sample_trace_chunks
 from repro.scenario import (
     Estimator,
+    FaultSpec,
     LengthSpec,
     Report,
     Scenario,
@@ -262,3 +264,53 @@ def test_chunked_sampling_equals_one_shot():
     np.testing.assert_array_equal(
         full.objects, np.concatenate([c.objects for c in chunks])
     )
+
+
+# ---------------------------------------------------------------------------
+# One Report builder for every Monte-Carlo path
+# ---------------------------------------------------------------------------
+MC_PATHS = {
+    "oneshot_single": dict(streaming=False),
+    "streamed_single": dict(streaming=True),
+    "sequential_ensemble": dict(streaming=False, replications=2),
+    "batched_xla_ensemble": dict(streaming=False, replications=2, backend="xla"),
+    "streamed_cluster": dict(streaming=True, nodes=3),
+}
+
+
+def _mc_path_scenario(streaming, replications=1, backend="c", nodes=1) -> Scenario:
+    faults = FaultSpec(events=((0.4, "fail", 1), (0.6, "recover", 1))) if nodes > 1 else None
+    return small_scenario(
+        workload=Workload(n_objects=300, alphas=(0.7, 0.9, 1.1)),
+        system=System(allocations=(16, 16, 16), physical_capacity=200,
+                      backend=backend, nodes=nodes, faults=faults),
+        estimator=Estimator("monte_carlo", streaming=streaming,
+                            replications=replications, chunk_size=2_000),
+        n_requests=6_000,
+        warmup=600,
+    )
+
+
+def _filled_fields(report) -> set:
+    return {k for k, v in report.to_dict().items() if v is not None} - {"ensemble"}
+
+
+@pytest.mark.parametrize("path", sorted(MC_PATHS))
+def test_every_monte_carlo_path_reports_the_same_fields(path):
+    """Single runs, one-shot or streamed, ensembles, sequential or in one
+    XLA program, and clusters fill the same Report fields, extras and
+    ripple statistics; a streamed run names its chunk size, an ensemble
+    adds its per-replica payload and a cluster its telemetry."""
+    kw = MC_PATHS[path]
+    rep = _mc_path_scenario(**kw).run()
+    base = _mc_path_scenario(streaming=False).run()
+    assert _filled_fields(rep) == _filled_fields(base)
+    assert set(rep.ripple) == set(base.ripple)
+    assert set(rep.extras) == (
+        {"n_hit_list", "n_hit_cache", "n_miss", "streaming"}
+        | ({"chunk_size"} if kw["streaming"] else set())
+        | ({"cluster"} if kw.get("nodes", 1) > 1 else set())
+    )
+    assert rep.extras["streaming"] is kw["streaming"]
+    assert (rep.ensemble is not None) == (kw.get("replications", 1) > 1)
+    assert rep.hit_prob_is_sparse == kw["streaming"]

@@ -167,17 +167,14 @@ def _lowered_name(fn, *args):
     return text.split("module @", 1)[1].split(" ", 1)[0]
 
 
-@pytest.mark.parametrize("name", ["jit_mc_drive", "jit_mc_drive_lanes", "jit_ws_solve",
-                                  "jit_ws_solve_batch"])
+@pytest.mark.parametrize("name", ["jit_mc_drive", "jit_mc_drive_lanes", "jit_ws_solve"])
 def test_executables_carry_fixed_names(name):
     import jax.numpy as jnp
 
     J, N, m = 3, 50, 16
-    if name.startswith("jit_ws"):
-        batched = name.endswith("batch")
-        fn = workingset._jitted_solver("L1", 8, 5, 10, 0.7, 1e-7, batched)
-        K = 2 if batched else 1
-        args = (jnp.ones((J * N + N + K * J,)), J, N)
+    if name == "jit_ws_solve":
+        fn = workingset._jitted_solver("L1", 8, 5, 10, 0.7, 1e-7)
+        args = (jnp.ones((J * N + N + J,)), J, N)
     else:
         lanes = name.endswith("lanes")
         fn = (fastsim_jax._batched_fn if lanes else fastsim_jax._single_fn)(True, N)

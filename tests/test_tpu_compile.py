@@ -173,16 +173,10 @@ def test_batched_drive_compiles_at_table1_size(one_chip):
     fn.lower(*_drive_args(one_chip, 3, N, 100_000, R=8)).compile()
 
 
-def test_batched_solver_compiles_at_table2_size(one_chip):
-    # Table II: the 8 allocation combinations of a J=3, N=1000 system
-    J, N, K = 3, 1000, 8
-    fn = workingset._jitted_solver("L1", 8, 200, 90, 0.7, 1e-7, True)
-    fn.lower(_spec(one_chip, (J * N + N + K * J,), jnp.float32), J, N).compile()
-
-
 def test_single_solver_compiles_at_table2_size(one_chip):
-    # one allocation vector of the same system, one packed input vector
+    # Table II: one allocation vector of a J=3, N=1000 system, one packed
+    # input vector
     J, N = 3, 1000
-    fn = workingset._jitted_solver("L1", 8, 200, 90, 0.7, 1e-7, False)
+    fn = workingset._jitted_solver("L1", 8, 200, 90, 0.7, 1e-7)
     fn.lower(_spec(one_chip, (J * N + N + J,), jnp.float32), J, N).compile()
 

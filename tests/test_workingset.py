@@ -22,7 +22,6 @@ from repro.core import (
     expected_inverse_one_plus,
     rate_matrix,
     solve_workingset,
-    solve_workingset_batch,
     solve_workingset_unshared,
 )
 
@@ -119,20 +118,6 @@ def test_eq9_guard():
         solve_workingset(lam, np.ones(100), np.array([60.0, 10.0]))
 
 
-def test_batch_solver_matches_sequential():
-    """One vmap-ed jit over a b-grid == per-combo solves (Table II path)."""
-    lam = rate_matrix(300, [0.75, 0.5, 1.0])
-    lengths = np.ones(300)
-    grid = np.array([(8.0, 8.0, 8.0), (8.0, 64.0, 8.0), (64.0, 64.0, 64.0)])
-    batch = solve_workingset_batch(lam, lengths, grid, attribution="L1")
-    assert len(batch) == 3
-    for b, sol in zip(grid, batch):
-        assert sol.converged
-        seq = solve_workingset(lam, lengths, b, attribution="L1")
-        assert np.allclose(sol.h, seq.h, atol=5e-5)
-        assert np.max(np.abs(sol.residual)) < 1e-2 * b.max()
-
-
 TABLE2_N = 1000
 TABLE2_ALPHAS = (0.75, 0.5, 1.0)
 FIG2_ALPHAS = tuple(0.5 + 0.5 * i for i in range(9))
@@ -179,12 +164,8 @@ def _j9_lengths(n):
 PACKED_CASES = {
     **{f"table2_single_{i}": ("table2", b, False)
        for i, b in enumerate(itertools.product((8.0, 64.0), repeat=3))},
-    "table2_batch": ("table2", list(itertools.product((8.0, 64.0), repeat=3)), False),
     "j9_lengths_single": ("j9", (12.0,) * 3 + (25.0,) * 3 + (90.0,) * 3, False),
-    "j9_lengths_batch": ("j9", [(12.0,) * 3 + (25.0,) * 3 + (90.0,) * 3,
-                               (6.0,) * 3 + (50.0,) * 3 + (40.0,) * 3], False),
     "table2_x64_single": ("table2", (8.0, 64.0, 8.0), True),
-    "table2_x64_batch": ("table2", [(8.0, 64.0, 8.0), (64.0, 8.0, 64.0)], True),
 }
 
 
@@ -199,32 +180,24 @@ def test_packed_inputs_match_three_arrays(case):
     else:
         lam, lengths = _j9_lengths(2000)
     b = np.asarray(b)
-    batched = b.ndim == 2
     tol, n_quad = 1e-7, max(8, (lam.shape[0] + 1) // 2 + 1)
     with jax.enable_x64(x64):
-        if batched:
-            sols = solve_workingset_batch(lam, lengths, b, attribution="L1")
-        else:
-            sols = [solve_workingset(lam, lengths, b, attribution="L1")]
+        sol = solve_workingset(lam, lengths, b, attribution="L1")
         solve = functools.partial(
             workingset._solve_jax, kind="L1", n_quad=n_quad, n_outer=200,
             n_bisect=90, damping=0.7, tol=tol,
         )
-        if batched:
-            solve = jax.vmap(solve, in_axes=(None, None, 0))
         dtype = jnp.float64 if x64 else jnp.float32
         t, h, L, res, iters, delta, _ = jax.jit(solve)(
             *(jnp.asarray(x, dtype) for x in (lam, lengths, b))
         )
-        want_converged = np.asarray(delta <= tol)
+        want_converged = bool(delta <= tol)
     assert t.dtype == dtype
-    lanes = range(len(b)) if batched else [...]
-    for sol, k in zip(sols, lanes):
-        for got, want in ((sol.t, t), (sol.h, h), (sol.L, L), (sol.residual, res)):
-            assert got.dtype == np.float64
-            assert np.array_equal(got, np.asarray(want[k], np.float64))
-        assert sol.iterations == int(iters[k])
-        assert sol.converged is bool(want_converged[k])
+    for got, want in ((sol.t, t), (sol.h, h), (sol.L, L), (sol.residual, res)):
+        assert got.dtype == np.float64
+        assert np.array_equal(got, np.asarray(want, np.float64))
+    assert sol.iterations == int(iters)
+    assert sol.converged is want_converged
 
 
 @pytest.mark.parametrize("step", [-1, 0, 1])
